@@ -12,6 +12,8 @@ import sys
 import time
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 MODULES = [
     "fig2_amplification",
     "fig3_compression",
@@ -38,6 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     mods = [args.only] if args.only else MODULES
     quick = not args.full
     failures = []

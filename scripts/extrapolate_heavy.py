@@ -29,7 +29,7 @@ L_SMALL, L_BIG = 2, 6
 def measure(cfg, shape, train_cfg, mesh):
     model = build_model(cfg)
     step, args, in_sh = build_step(model, shape, train_cfg, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, in_shardings=in_sh).lower(*args).compile()
     return analyze_compiled(
         compiled, arch=cfg.arch_id, shape=shape.name, mesh_name="16x16",

@@ -4,7 +4,7 @@ Huffman entropy coding (Sec. III-B).
 Edge side: the two-phase device-resident batched encode of
 ``repro.kernels.entropy`` — one histogram dispatch (only the
 ``(B, 2^bits)`` counts reach the host, where the canonical table is
-built) and one fused quantize + LUT-gather + scan + pack ``pallas_call``
+built) and one fused quantize + LUT-gather + scan + pack XLA program
 that emits the packed bitstream words. Quantized codes never touch HBM
 or the PCIe link. Pathological deep-tree distributions (any code longer
 than ``PACK_MAX_CODE_BITS``) fall back to the host reference encoder in

@@ -74,20 +74,24 @@ class DecoupledRunner:
     model: Model
     params: Any
     plan: DecoupledPlan
-    # Optional repro.serving.meshed.MeshedCloudWorker: when set,
-    # cloud_step_batch routes batchable groups through the sharded mesh
-    # tail (see cloud_step_batch).
+    # Optional repro.serving.meshed.MeshedCloudWorker: when set, every
+    # cloud step runs the worker's sharded tail (see cloud_step_batch).
     mesh_worker: Optional[Any] = None
+    # What the edge half reads; ``None`` means ``params``. A runner of a
+    # meshed cloud gets only the head's slice (``Model.head_params``).
+    edge_params: Any = None
 
     def __post_init__(self):
         from repro.codec import get_codec
 
+        if self.edge_params is None:
+            self.edge_params = self.params
         self._head = jax.jit(self.model.run_head, static_argnums=2)
         self._tail = jax.jit(self.model.run_tail, static_argnums=2)
         self._codec: "BoundaryCodec" = get_codec(self.plan.codec)
 
     def edge_step(self, batch) -> Tuple["WireBlob", Any]:
-        out = self._head(self.params, batch, self.plan.point)
+        out = self._head(self.edge_params, batch, self.plan.point)
         boundary, extras = out if isinstance(out, tuple) else (out, None)
         blob = self._codec.encode(boundary, self.plan.bits)
         return blob, extras
@@ -97,7 +101,7 @@ class DecoupledRunner:
         every boundary in **one** batched codec launch (same-shape
         boundaries stack; the codec falls back to a loop otherwise). Each
         blob is byte-identical to the per-request ``edge_step``."""
-        outs = [self._head(self.params, b, self.plan.point)
+        outs = [self._head(self.edge_params, b, self.plan.point)
                 for b in batches]
         pairs = [o if isinstance(o, tuple) else (o, None) for o in outs]
         blobs = self._codec.encode_batch([p[0] for p in pairs],
@@ -107,6 +111,8 @@ class DecoupledRunner:
     def cloud_step(self, blob: "WireBlob", extras=None):
         from repro.codec import get_codec
 
+        if self.mesh_worker is not None:
+            return self.mesh_worker.cloud_step(blob, extras, self.plan)
         dtype = jnp.dtype(self.model.cfg.dtype)
         boundary = get_codec(blob.codec).decode(blob, out_dtype=dtype)
         if extras is not None:
@@ -140,9 +146,9 @@ class DecoupledRunner:
         params across the whole mesh. That path is inherently fused —
         same float-equivalence contract as ``fuse_tail=True`` — and can
         additionally batch same-structure ``extras`` (transformer
-        position/encoder trees). Groups the worker cannot shard
-        (mixed codecs, non-stackable extras) fall through to the
-        single-device logic below."""
+        position/encoder trees). Groups the worker cannot batch-shard
+        (mixed codecs, non-stackable extras) go through it one blob at a
+        time (``cloud_step``): the tail params live only on the mesh."""
         from repro.codec import get_codec
 
         if extras_list is None:
@@ -155,7 +161,8 @@ class DecoupledRunner:
             if out is not None:
                 return out
         batchable = (
-            len(blobs) > 1
+            self.mesh_worker is None
+            and len(blobs) > 1
             and all(e is None for e in extras_list)
             and len({b.codec for b in blobs}) == 1
             and len({b.shape[1:] for b in blobs}) == 1
@@ -456,9 +463,11 @@ class JaladEngine:
             _stream_terms=None, _tri_space=tri, cloud_mesh=mesh_model)
 
     def make_runner(self, params, plan: DecoupledPlan,
-                    mesh_worker: Optional[Any] = None) -> DecoupledRunner:
+                    mesh_worker: Optional[Any] = None,
+                    edge_params: Any = None) -> DecoupledRunner:
         return DecoupledRunner(self.model, params, plan,
-                               mesh_worker=mesh_worker)
+                               mesh_worker=mesh_worker,
+                               edge_params=edge_params)
 
     def make_tri_runner(self, params,
                         plan: DecoupledPlan) -> TriDecoupledRunner:

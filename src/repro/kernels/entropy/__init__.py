@@ -1,8 +1,8 @@
 """Device-resident entropy-codec kernels.
 
 The two-phase batched Huffman encode (one histogram dispatch + one
-fused quantize/LUT-gather/scan/pack ``pallas_call``) behind
-``repro.codec.huffman``. See ``docs/kernels.md`` for the grid layout
+fused quantize/LUT-gather/scan/pack XLA program) behind
+``repro.codec.huffman``. See ``docs/kernels.md`` for the stream layout
 and the byte-identity contract with ``repro.core.entropy``.
 """
 from repro.kernels.entropy.ops import (

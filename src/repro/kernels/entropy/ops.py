@@ -15,11 +15,10 @@ on the host and moves everything O(n) onto the device, batched:
   canonical table; it is flattened into per-sample ``(code, length)``
   LUT arrays and each sample's exact ``total_bits`` (known before the
   pack launches, so the output width is static).
-* **Phase 2 — pack kernel.** One ``pallas_call``
-  (``huffman.huffman_pack_blocks``) re-quantizes the tiles in-kernel,
-  gathers per-symbol (code, length), prefix-sums the bit lengths with
-  an SMEM carry across blocks, and scatters the shifted codes into
-  packed u32 words. Serializing those words big-endian and trimming to
+* **Phase 2 — pack dispatch.** One jitted XLA program
+  (``huffman.huffman_pack``) re-quantizes the stack, gathers
+  per-symbol (code, length), prefix-sums the bit lengths per sample,
+  and emits the shifted codes into packed u32 words. Serializing those words big-endian and trimming to
   ``ceil(total_bits / 8)`` bytes reproduces ``ent.huffman_encode``'s
   bitstream **byte-identically** (pinned in
   ``tests/test_entropy_kernel.py``).
@@ -29,8 +28,8 @@ through the shared ``kernels.quantize`` launch counter so
 ``count_launches`` sees both.
 
 Routing: pathological deep-tree distributions (any code length >
-``PACK_MAX_CODE_BITS``) and streams too long for the i32 bit-offset
-carry return ``None`` from :func:`huffman_encode_batch_device`; the
+``PACK_MAX_CODE_BITS``) and streams too long for the i32 bit offsets
+return ``None`` from :func:`huffman_encode_batch_device`; the
 codec then falls back to the host reference path, whose output is the
 identity the device path is pinned against anyway.
 """
@@ -46,25 +45,17 @@ import numpy as np
 from repro.core import entropy as ent
 from repro.kernels.entropy import huffman as hk
 from repro.kernels.quantize import quantize as k
-from repro.kernels.quantize.ops import _should_interpret
 
 LANES = k.LANES
 
-# A symbol may span at most two u32 words in the pack kernel's two-part
+# A symbol may span at most two u32 words in the pack's two-part
 # emission, so any code longer than 32 bits routes to the host reference
 # path. Reaching 33 bits needs a Fibonacci-like frequency skew over >
 # 5M elements — tests pin the routing by lowering this cap instead.
 PACK_MAX_CODE_BITS = 32
 
-# The kernel threads bit offsets through an int32 SMEM carry.
+# The pack computes stream bit offsets in int32.
 _MAX_TOTAL_BITS = (1 << 31) - 1
-
-# Row-block height for the pack kernel's 1-D grid. Deliberately larger
-# than the quantize kernels' DEFAULT_BLOCK_M: each interpret-mode grid
-# step re-enters the whole fused body, which measures ~1.8 ms of
-# overhead per extra step at paper scale, so a typical batch should run
-# as a single step (4096 rows = 512k elements per block).
-PACK_BLOCK_ROWS = 4096
 
 
 @functools.partial(jax.jit, static_argnames=("bits",))
@@ -73,7 +64,7 @@ def _hist_ranges(xb: jnp.ndarray, bits: int):
     stack in one launch. The quantize is re-traced exactly as
     ``core.quantization.quantize`` writes it (min/max are exactly
     associative), so the counted codes are bitwise the ones the pack
-    kernel re-derives and the host reference would emit."""
+    program re-derives and the host reference would emit."""
     xf = xb.astype(jnp.float32)
     mn = jnp.min(xf, axis=1)
     mx = jnp.max(xf, axis=1)
@@ -140,7 +131,7 @@ def _sample_table(freqs: np.ndarray, num_symbols: int):
     Returns ``(code_of u32 (S,), len_of i32 (S,), lengths (S,),
     total_bits)`` or ``None`` when the sample must route to the host
     reference path (a code longer than ``PACK_MAX_CODE_BITS``, or a
-    stream overflowing the kernel's i32 bit-offset carry). The code
+    stream overflowing the pack's i32 bit offsets). The code
     assignment is the numeric canonical form (``ent._canonical_ranges``
     — codes of length l start at first_code[l], ranked by symbol), which
     is exactly the sequential shift-and-increment of
@@ -160,6 +151,31 @@ def _sample_table(freqs: np.ndarray, num_symbols: int):
     return code_of, len_of, lengths, total_bits
 
 
+def fold_factor(max_len: int) -> int:
+    """Symbol folding factor for the pack: adjacent codes are
+    concatenated into super-symbols as long as the longest folded code
+    still fits a u32 word, so every per-element prefix sum runs over
+    n / fold entries. Known before launch from the host-built tables;
+    capped so the static trace count stays tiny."""
+    fold = 1
+    while fold < 16 and fold * 2 * max_len <= 32:
+        fold *= 2
+    return fold
+
+
+def stream_words(max_bits: int) -> int:
+    """Per-sample output width in u32 words. It quantizes coarsely
+    (powers of two up to 1024 words, then 1024-word steps) so small
+    data-dependent drift in total_bits between calls reuses the pack
+    executable's jit cache instead of re-tracing, without ballooning the
+    segment scan."""
+    need = (max_bits + 31) // 32
+    w_words = LANES
+    while w_words < need:
+        w_words = w_words * 2 if w_words < 1024 else w_words + 1024
+    return w_words
+
+
 def _pad_lanes(n: int) -> int:
     return max((n + LANES - 1) // LANES * LANES, LANES)
 
@@ -167,8 +183,6 @@ def _pad_lanes(n: int) -> int:
 def huffman_encode_batch_device(
     xb: jnp.ndarray,
     bits: int,
-    block_m: int = PACK_BLOCK_ROWS,
-    interpret: Optional[bool] = None,
 ) -> Optional[Tuple[List[bytes], np.ndarray, np.ndarray]]:
     """Batched device Huffman encode of a (B, *shape) float stack.
 
@@ -176,11 +190,10 @@ def huffman_encode_batch_device(
     byte-identical to ``ent.huffman_encode`` of that sample's quantized
     codes, plus the (B,) affine ranges for the blob headers — in two
     device dispatches total (histogram + pack). Returns ``None`` when
-    any sample needs the host reference path (see module docstring);
-    callers fall back per-tensor.
+    the stack is empty, or when any sample needs the host reference
+    path (see module docstring; those returns are counted in
+    ``quantize.HOST_ROUTE_COUNT``); callers fall back per-tensor.
     """
-    if interpret is None:
-        interpret = _should_interpret()
     xb = jnp.asarray(xb)
     bsz = xb.shape[0]
     n_elem = int(np.prod(xb.shape[1:])) if xb.ndim > 1 else 1
@@ -201,6 +214,7 @@ def huffman_encode_batch_device(
     for b in range(bsz):
         t = _sample_table(hist[b], num_symbols)
         if t is None:
+            k._host_routed()
             return None
         tables.append(t)
 
@@ -208,9 +222,9 @@ def huffman_encode_batch_device(
     max_bits = max(t[3] for t in tables)
     max_len = max(int(t[2].max()) for t in tables)
     # One u32 LUT entry per symbol — (length << 26) | code — whenever
-    # every code fits 26 bits, halving the kernel's per-element gather
-    # traffic; codes wider than that (only possible at fold == 1) keep
-    # separate code/length tables.
+    # every code fits 26 bits, halving the per-element gather traffic;
+    # codes wider than that (only possible at fold == 1) keep separate
+    # code/length tables.
     split_lut = max_len > 26
     code_lut = np.zeros((bsz, s_pad), np.uint32)
     len_lut = np.zeros((bsz, s_pad), np.uint32)
@@ -223,40 +237,22 @@ def huffman_encode_batch_device(
                 (len_of.astype(np.uint32) << 26) | code_of)
     if not split_lut:
         len_lut = code_lut
-    # Symbol folding factor for the pack kernel: adjacent codes are
-    # concatenated into super-symbols as long as the longest folded code
-    # still fits a u32 word, so every per-element prefix sum in the
-    # kernel runs over n / fold entries. Known before launch from the
-    # host-built tables; capped so the static trace count stays tiny.
-    fold = 1
-    while fold < 16 and fold * 2 * max_len <= 32:
-        fold *= 2
-    # The output width quantizes coarsely (powers of two up to 1024
-    # words, then 1024-word steps) so small data-dependent drift in
-    # total_bits between calls reuses the pack executable's jit cache
-    # instead of re-tracing, without ballooning the segment scan.
-    need = (max_bits + 31) // 32
-    w_words = LANES
-    while w_words < need:
-        w_words = w_words * 2 if w_words < 1024 else w_words + 1024
-    # The pack kernel runs the whole batch as one concatenated stream
-    # with sample b's bits based at 32 * w_words * b, so the last
-    # stream position must also fit the i32 offset arithmetic.
+    fold = fold_factor(max_len)
+    w_words = stream_words(max_bits)
+    # The pack runs the whole batch as one concatenated stream with
+    # sample b's bits based at 32 * w_words * b, so the last stream
+    # position must also fit the i32 offset arithmetic.
     if 32 * w_words * bsz > _MAX_TOTAL_BITS:
+        k._host_routed()
         return None
-    prev = np.concatenate(
-        [[0], np.cumsum([t[3] for t in tables[:-1]], dtype=np.int64)])
-    base_bits = (32 * np.int64(w_words) * np.arange(bsz, dtype=np.int64)
-                 - prev).astype(np.int32)
 
-    # Dispatch 2: the fused quantize + LUT gather + scan + pack kernel
+    # Dispatch 2: the fused quantize + LUT gather + scan + pack program
     # (jitted — counted here, where every call really dispatches it).
     k._launched()
-    words = np.asarray(hk.huffman_pack_blocks(
-        xb.reshape(bsz, -1), mn_dev, scale, jnp.asarray(base_bits),
-        w_words, jnp.asarray(code_lut), jnp.asarray(len_lut),
-        bits=bits, n_elem=n_elem, block_m=block_m, fold=fold,
-        split_lut=split_lut, interpret=interpret,
+    words = np.asarray(hk.huffman_pack(
+        xb.reshape(bsz, -1), mn_dev, scale, jnp.asarray(code_lut),
+        jnp.asarray(len_lut), w_words=w_words, bits=bits, fold=fold,
+        split_lut=split_lut,
     ))
 
     # Host framing only: header + big-endian word bytes trimmed to the

@@ -23,6 +23,7 @@ from repro.kernels.quantize.ops import (
     perchannel_words,
     quantize_dequantize_kernel,
     count_launches,
+    count_host_routes,
 )
 
 __all__ = [
@@ -43,4 +44,5 @@ __all__ = [
     "perchannel_words",
     "quantize_dequantize_kernel",
     "count_launches",
+    "count_host_routes",
 ]

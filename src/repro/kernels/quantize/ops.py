@@ -34,20 +34,30 @@ def _should_interpret() -> bool:
 
 
 @contextlib.contextmanager
-def count_launches():
-    """Count pallas_call dispatches issued inside the block. Only eager
-    (un-jitted ``*_impl``) calls dispatch per invocation — under jit the
-    launches happen once at trace time — so measure against the impls."""
-
+def _counting(counter: str):
     class _Box:
         count = 0
 
     box = _Box()
-    start = k.LAUNCH_COUNT
+    start = getattr(k, counter)
     try:
         yield box
     finally:
-        box.count = k.LAUNCH_COUNT - start
+        box.count = getattr(k, counter) - start
+
+
+def count_launches():
+    """Count pallas_call dispatches issued inside the block. Only eager
+    (un-jitted ``*_impl``) calls dispatch per invocation — under jit the
+    launches happen once at trace time — so measure against the impls."""
+    return _counting("LAUNCH_COUNT")
+
+
+def count_host_routes():
+    """Count batched Huffman encodes inside the block that left the
+    device path for the per-tensor host encoder (deep trees, overlong
+    streams)."""
+    return _counting("HOST_ROUTE_COUNT")
 
 
 def _sublane(bits: int) -> int:
@@ -83,33 +93,56 @@ def _to_tiles(x: jnp.ndarray, block_m: int, bits: int = 0
               ) -> Tuple[jnp.ndarray, int]:
     """Flatten to (M, 128) and pad M to a block multiple. Returns the padded
     2-D array and the original element count."""
-    n_elem = x.size
-    flat = x.reshape(-1)
-    cols = LANES
-    rows_pad = _tile_rows(n_elem, block_m, bits)
-    pad = rows_pad * cols - n_elem
-    # Pad with the first element so padding never changes min/max (zeros
-    # for an empty input, which has no min/max to preserve).
-    fill = flat[0] if n_elem else jnp.zeros((), flat.dtype)
-    flat = jnp.concatenate([flat, jnp.full((pad,), fill, flat.dtype)])
-    return flat.reshape(rows_pad, cols), n_elem
+    x3d, n_elem = _to_tiles_batch(x.reshape(1, -1), block_m, bits)
+    return x3d[0], n_elem
+
+
+def _pad_rows(flat: jnp.ndarray, cols: int, rows_pad: int,
+              fill_first: bool) -> jnp.ndarray:
+    """(B, n) -> (B, rows_pad, cols), n > 0. The padding repeats each
+    sample's first element when ``fill_first`` (so it never changes the
+    sample's min/max), else it is zero.
+
+    The whole ``cols``-wide rows are relaid out on their own, behind an
+    ``optimization_barrier``, and the padding is read from that copy:
+    fused into the concatenate, or read from the flat view, the relayout
+    of a large feature map (e.g. (8, 256, 56, 56) f32) takes the TPU
+    compiler minutes."""
+    bsz, n = flat.shape
+    full = n // cols
+    tail = n - full * cols
+    parts = []
+    if full:
+        parts.append(jax.lax.optimization_barrier(
+            flat[:, :full * cols].reshape(bsz, full, cols)))
+        fill = parts[0][:, 0, :1]
+    else:
+        fill = flat[:, :1]
+    if not fill_first:
+        fill = jnp.zeros_like(fill)
+    if tail:
+        pad = jnp.broadcast_to(fill, (bsz, cols - tail))
+        row = jnp.concatenate([flat[:, full * cols:], pad], axis=1)
+        parts.append(row[:, None, :])
+    used = full + (1 if tail else 0)
+    if rows_pad > used:
+        parts.append(jnp.broadcast_to(fill[:, :, None],
+                                      (bsz, rows_pad - used, cols)))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
 def _to_tiles_batch(xb: jnp.ndarray, block_m: int, bits: int = 0
                     ) -> Tuple[jnp.ndarray, int]:
-    """Batched ``_to_tiles``: (B, *shape) -> (B, M, 128), padding each
-    sample with its own first element (per-sample min/max preserved)."""
+    """(B, *shape) -> (B, M, 128), padding each sample with its own first
+    element so padding never changes its min/max (zeros for an empty
+    input, which has no min/max to preserve)."""
     bsz = xb.shape[0]
     n_elem = int(np.prod(xb.shape[1:])) if xb.ndim > 1 else 1
     flat = xb.reshape(bsz, -1)
     rows_pad = _tile_rows(n_elem, block_m, bits)
-    pad = rows_pad * LANES - n_elem
-    if n_elem:
-        fill = jnp.broadcast_to(flat[:, :1], (bsz, pad))
-    else:
-        fill = jnp.zeros((bsz, pad), flat.dtype)
-    flat = jnp.concatenate([flat, fill], axis=1)
-    return flat.reshape(bsz, rows_pad, LANES), n_elem
+    if not n_elem:
+        return jnp.zeros((bsz, rows_pad, LANES), flat.dtype), n_elem
+    return _pad_rows(flat, LANES, rows_pad, True), n_elem
 
 
 # ---------------------------------------------------------------------------
@@ -338,10 +371,9 @@ def _wire_tiles(codes_flat: jnp.ndarray, n_elem: int, bits: int,
     cols = LANES // 2 if bits <= 4 else LANES
     rows_pad = _tile_rows(n_elem, block_m, bits)
     lead = codes_flat.shape[:-1]
-    flat = codes_flat.reshape(lead + (-1,))
-    pad = [(0, 0)] * len(lead) + [(0, rows_pad * cols - flat.shape[-1])]
-    flat = jnp.pad(flat, pad)
-    return flat.reshape(lead + (rows_pad, cols))
+    flat = codes_flat.reshape((-1, codes_flat.shape[-1]))
+    return _pad_rows(flat, cols, rows_pad, False).reshape(
+        lead + (rows_pad, cols))
 
 
 def dequantize_wire_impl(codes_flat, mn, mx, bits, shape,
@@ -421,6 +453,20 @@ def dequantize_wire_batch(
                                       block_m, interpret, out_dtype)
 
 
+def shard_batch(fn, mesh, batch_axis: str = "data"):
+    """``fn(codes, mn, mx)`` — a batched decode whose operands and result
+    all lead with the sample axis — run on each device's block of that
+    axis. XLA cannot partition a Mosaic kernel, so a caller whose
+    operands are sharded hands each device its own block
+    (``shard_map``); devices along the other mesh axes decode the same
+    block."""
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(batch_axis)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
+
+
 @functools.lru_cache(maxsize=None)
 def _wire_decode_sharded_fn(mesh, batch_axis, bits, shape, block_m,
                             interpret, out_dtype):
@@ -434,7 +480,8 @@ def _wire_decode_sharded_fn(mesh, batch_axis, bits, shape, block_m,
         return dequantize_wire_batch_impl(codes_flat, mn, mx, bits, shape,
                                           block_m, interpret, out_dtype)
 
-    return jax.jit(fn, in_shardings=(codes, row, row), out_shardings=out)
+    return jax.jit(shard_batch(fn, mesh, batch_axis),
+                   in_shardings=(codes, row, row), out_shardings=out)
 
 
 def dequantize_wire_batch_sharded(

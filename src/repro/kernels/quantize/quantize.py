@@ -6,13 +6,13 @@ packing. PR 2 ran it as a three-``pallas_call`` chain (minmax -> quantize
 -> pack4) that read the feature map from HBM twice and round-tripped the
 codes a third time for packing. The encode is now **one launch**:
 
-  1. ``fused_encode_blocks``   — a single two-phase ``pallas_call`` over a
-     ``(2, B, M // block_m)`` grid. Phase 0 is a hierarchical grid
-     reduction: each step reduces its VMEM tile on the VPU and folds the
-     result into a per-sample ``(B, 2)`` SMEM accumulator that persists
-     across grid steps. Phase 1 re-streams the same tiles through the
-     fused affine-map + round + clip (+ nibble pack for bits <= 4) body —
-     codes never touch HBM between the affine map and the pack.
+  1. ``fused_encode_blocks``   — a single ``pallas_call``. Small stacks
+     run as one whole-batch VMEM tile (reduce, then quantize + pack, per
+     sample); large stacks run a two-phase ``(2, B, M // block_m)`` grid
+     whose phase 0 folds each tile's (min, max) into an SMEM accumulator
+     and whose phase 1 re-streams the tiles through the fused affine map
+     + round + clip (+ nibble pack for bits <= 4) — codes never touch
+     HBM between the affine map and the pack.
   2. ``fused_decode_blocks``   — the symmetric cloud half: (nibble unpack
      +) dequant + cast in one launch, batched over a leading sample axis
      with per-sample ``(min, step)`` scalars.
@@ -23,18 +23,25 @@ codes a third time for packing. The encode is now **one launch**:
 
 Every kernel carries a leading batch axis, so one launch encodes/decodes
 a stack of B boundary tensors (the serving pipeline's micro-batched edge
-encode) with per-sample scalars/vectors selected by the grid index map.
+encode). Per-sample scalars live in SMEM (full-array operands and
+outputs indexed by the sample id); per-channel range vectors ride as
+``(cb, 1)`` VMEM columns.
+
+What Mosaic accepts shapes the bodies: floats reach unsigned code types
+through ``int32``; lanes are never sliced with a stride, so the nibble
+pack/unpack is an exact bf16 MXU contraction against a constant 0/1/16
+selection matrix, and the per-channel kernels receive their codes
+word-slot-major (the wrapper lays ``(B, per_word, C, W)`` out in XLA).
 
 The PR 2 three-launch chain (``minmax_blocks`` -> ``quantize_blocks`` ->
-``pack4_blocks``) is kept verbatim below as the *reference path*: tests
-pin the fused kernel's output byte-for-byte against it, and
-``benchmarks/codec.py`` asserts the fused path is strictly faster.
+``pack4_blocks``) is kept below as the *reference path*: tests pin the
+fused kernel's output byte-for-byte against it, and
+``benchmarks/codec.py`` uses it as the baseline.
 
 Tiles are (block_m, 128)-shaped: the trailing 128 matches the VPU lane
-width; block_m is a multiple of 8 (f32 sublane) chosen so a tile fits
-comfortably in VMEM. On this CPU-only container the kernels are validated
-with ``interpret=True`` against ``ref.py``; on real TPUs the same
-``pl.pallas_call`` lowers to Mosaic.
+width; block_m is a multiple of 32 (the uint8 code tile). Tests run the
+kernels with ``interpret=True`` against ``ref.py`` and compile them for
+a described v5e chip (``tests/test_tpu_compile.py``).
 
 See ``docs/kernels.md`` for the tiling scheme and validation story.
 """
@@ -64,6 +71,12 @@ PC_TILE_BYTES = 1 << 20
 # every launcher below). ``benchmarks/codec.py`` reads it through
 # ``ops.count_launches`` to report launches-per-encode for each codec path.
 LAUNCH_COUNT = 0
+# Batched Huffman encodes that left the device path for the per-tensor
+# host encoder (``kernels.entropy.ops.huffman_encode_batch_device``
+# returned None); read through ``ops.count_host_routes``.
+HOST_ROUTE_COUNT = 0
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _launched() -> None:
@@ -71,66 +84,105 @@ def _launched() -> None:
     LAUNCH_COUNT += 1
 
 
+def _host_routed() -> None:
+    global HOST_ROUTE_COUNT
+    HOST_ROUTE_COUNT += 1
+
+
 def code_dtype(bits: int):
     """Narrowest unsigned integer dtype that holds a c-bit code."""
     return jnp.uint8 if bits <= 8 else jnp.uint16
 
 
+# The nibble pack and unpack are lane selections written as bf16 matmuls
+# against constant 0/1/16 matrices: codes <= 15 and the entries are exact
+# in bf16 and every dot sums at most two products <= 255 in f32, so the
+# contraction is exact.
+def _nibble_pack_matrix(lanes: int) -> jnp.ndarray:
+    """(lanes, lanes / 2): lane 2j -> byte j times 1, lane 2j + 1 -> byte
+    j times 16."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (lanes, lanes // 2), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (lanes, lanes // 2), 1)
+    sel = jnp.where(r == 2 * c, 1.0, jnp.where(r == 2 * c + 1, 16.0, 0.0))
+    return sel.astype(jnp.bfloat16)
+
+
+def _nibble_unpack_matrix(half: int, odd: bool) -> jnp.ndarray:
+    """(half, 2 * half): byte j -> lane 2j, or lane 2j + 1 when ``odd``."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (half, 2 * half), 1)
+    return jnp.where(c == 2 * r + int(odd), 1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _pack_lanes(q: jnp.ndarray, bits: int, out_dtype) -> jnp.ndarray:
+    """Fused tail of the encode body: (rows, 128) integer-valued f32 codes
+    -> two codes per byte (bits <= 4, lane pairs (2j, 2j + 1) -> byte j,
+    low nibble first) or one code per element, cast through int32."""
+    if bits <= 4:
+        q = jnp.dot(q.astype(jnp.bfloat16), _nibble_pack_matrix(LANES),
+                    preferred_element_type=jnp.float32)
+    return q.astype(jnp.int32).astype(out_dtype)
+
+
+def _unpack_nibbles(q: jnp.ndarray) -> jnp.ndarray:
+    """(rows, 64) int32 packed bytes -> (rows, 128) f32 codes in lane
+    order [lo0, hi0, lo1, hi1, ...] (the inverse of ``_pack_lanes``)."""
+    lo = (q & 0x0F).astype(jnp.float32).astype(jnp.bfloat16)
+    hi = (q >> 4).astype(jnp.float32).astype(jnp.bfloat16)
+    half = q.shape[-1]
+    return (jnp.dot(lo, _nibble_unpack_matrix(half, False),
+                    preferred_element_type=jnp.float32)
+            + jnp.dot(hi, _nibble_unpack_matrix(half, True),
+                      preferred_element_type=jnp.float32))
+
+
+def _encode_tile(blk: jnp.ndarray, mn, mx, bits: int, out_dtype):
+    """Affine map + round + clip (+ pack) of one f32 tile against its
+    sample's (min, max) — the math of ``core.quantization.quantize``."""
+    levels = float((1 << bits) - 1)
+    scale = jnp.where(mx > mn, levels / (mx - mn), 0.0)
+    q = jnp.clip(jnp.round((blk - mn) * scale), 0.0, levels)
+    return _pack_lanes(q, bits, out_dtype)
+
+
 # ---------------------------------------------------------------------------
-# Fused single-launch edge encode (batched): hierarchical min/max reduction
-# feeding quantize (+ pack4) in one pallas_call
+# Fused single-launch edge encode (batched): min/max reduction feeding
+# quantize (+ pack4) in one pallas_call
 # ---------------------------------------------------------------------------
 
 
 # Whole-batch tile budget, in (sublane) rows: below this the entire
-# (B, M, 128) stack is one VMEM tile per phase — f32 4096 x 128 = 2 MiB —
-# and the grid collapses to (2, 1, 1).
+# (B, M, 128) stack is one VMEM tile — f32 4096 x 128 = 2 MiB — and the
+# kernel runs as a single grid step.
 WHOLE_TILE_ROWS = 4096
 
 
-def _pack_lanes(q: jnp.ndarray, bits: int, out_dtype) -> jnp.ndarray:
-    """Fused tail of the encode body: round-tripped nowhere — codes go
-    straight from the affine map to nibble pairs (bits <= 4) or a cast."""
-    if bits <= 4:
-        qq = q.astype(jnp.uint8)
-        return qq[..., 0::2] | (qq[..., 1::2] << 4)
-    return q.astype(out_dtype)
+def _fused_encode_whole_kernel(x_ref, out_ref, mn_ref, mx_ref, *, bits: int):
+    """Whole-batch variant: the (B, M, 128) stack is one VMEM tile; each
+    sample is reduced and then quantized from VMEM, its (min, max)
+    written to the SMEM range outputs."""
 
+    def sample(b, carry):
+        blk = x_ref[b].astype(jnp.float32)
+        mn = jnp.min(blk)
+        mx = jnp.max(blk)
+        mn_ref[b] = mn
+        mx_ref[b] = mx
+        out_ref[b] = _encode_tile(blk, mn, mx, bits, out_ref.dtype)
+        return carry
 
-def _fused_encode_whole_kernel(x_ref, out_ref, mn_ref, mx_ref,
-                               *, bits: int):
-    """Whole-batch variant: one (B, M, 128) tile per phase, per-sample
-    (min, max) vectors accumulated directly in the revisited range
-    outputs (their constant index map keeps them resident in VMEM across
-    the whole two-step grid)."""
-    p = pl.program_id(0)
-    blk = x_ref[...].astype(jnp.float32)
-    levels = float((1 << bits) - 1)
-
-    @pl.when(p == 0)
-    def _reduce():
-        mn_ref[:, 0] = jnp.min(blk, axis=(1, 2))
-        mx_ref[:, 0] = jnp.max(blk, axis=(1, 2))
-
-    @pl.when(p == 1)
-    def _quantize():
-        mn = mn_ref[:, 0][:, None, None]
-        mx = mx_ref[:, 0][:, None, None]
-        scale = jnp.where(mx > mn, levels / (mx - mn), 0.0)
-        q = jnp.clip(jnp.round((blk - mn) * scale), 0.0, levels)
-        out_ref[...] = _pack_lanes(q, bits, out_ref.dtype)
+    jax.lax.fori_loop(0, x_ref.shape[0], sample, 0)
 
 
 def _fused_encode_kernel(x_ref, out_ref, mn_ref, mx_ref, acc_ref,
                          *, bits: int):
     """Blocked variant (large stacks): two-phase grid — p=0 reduces
     min/max into the SMEM accumulator, p=1 quantizes (+ packs) against
-    the finished per-sample scalars."""
+    the finished per-sample scalars and publishes them."""
     p = pl.program_id(0)
     b = pl.program_id(1)
     i = pl.program_id(2)
-    blk = x_ref[...][0].astype(jnp.float32)
-    levels = float((1 << bits) - 1)
+    blk = x_ref[0].astype(jnp.float32)
 
     @pl.when(p == 0)
     def _reduce():
@@ -139,27 +191,21 @@ def _fused_encode_kernel(x_ref, out_ref, mn_ref, mx_ref, acc_ref,
 
         @pl.when(i == 0)
         def _init():
-            acc_ref[b, 0] = bmin
-            acc_ref[b, 1] = bmax
+            acc_ref[0, b] = bmin
+            acc_ref[1, b] = bmax
 
         @pl.when(i > 0)
         def _fold():
-            acc_ref[b, 0] = jnp.minimum(acc_ref[b, 0], bmin)
-            acc_ref[b, 1] = jnp.maximum(acc_ref[b, 1], bmax)
+            acc_ref[0, b] = jnp.minimum(acc_ref[0, b], bmin)
+            acc_ref[1, b] = jnp.maximum(acc_ref[1, b], bmax)
 
     @pl.when(p == 1)
     def _quantize():
-        mn = acc_ref[b, 0]
-        mx = acc_ref[b, 1]
-
-        @pl.when(i == 0)
-        def _emit_range():
-            mn_ref[0, 0] = mn
-            mx_ref[0, 0] = mx
-
-        scale = jnp.where(mx > mn, levels / (mx - mn), 0.0)
-        q = jnp.clip(jnp.round((blk - mn) * scale), 0.0, levels)
-        out_ref[...] = _pack_lanes(q, bits, out_ref.dtype)[None]
+        mn = acc_ref[0, b]
+        mx = acc_ref[1, b]
+        mn_ref[b] = mn
+        mx_ref[b] = mx
+        out_ref[0] = _encode_tile(blk, mn, mx, bits, out_ref.dtype)
 
 
 def fused_encode_blocks(x3d: jnp.ndarray, bits: int, block_m: int,
@@ -168,58 +214,48 @@ def fused_encode_blocks(x3d: jnp.ndarray, bits: int, block_m: int,
     """One launch: (B, M, 128) tiles -> (codes (B, M, W), mn (B,), mx (B,)).
 
     W is 64 (two int4 codes per byte) when bits <= 4, else 128. The
-    leading grid axis is the phase: the input streams through the kernel
-    twice (hierarchical min/max reduction pass, then the fused quantize +
-    pack map pass) inside a single pallas_call, with the per-sample
-    (min, max) carried between phases on-chip — codes never touch HBM
-    between the affine map and the pack.
+    per-sample (min, max) are carried between the reduction and the
+    quantize on-chip — codes never touch HBM between the affine map and
+    the pack — and leave through SMEM outputs.
 
     Stacks up to ``WHOLE_TILE_ROWS`` total rows run as one (B, M, 128)
-    tile per phase (grid (2, 1, 1)), the per-sample ranges living in the
-    revisited (B, 1) output blocks. Larger stacks tile (block_m, 128) per
-    sample with an SMEM scratch accumulator; their codes output pins
-    block (0, 0, 0) during phase 0 and is rewritten by phase 1's first
-    step, so the extra flush is free.
+    tile in a single grid step. Larger stacks tile (block_m, 128) per
+    sample over a ``(2, B, M // block_m)`` grid whose leading axis is the
+    phase (reduce, then quantize); their codes output pins block
+    (0, 0, 0) during phase 0 and is rewritten by phase 1's first step,
+    so the extra flush is free.
     """
     bsz, m, n = x3d.shape
     pack = bits <= 4
     out_n = n // 2 if pack else n
     out_shape = [
         jax.ShapeDtypeStruct((bsz, m, out_n), code_dtype(bits)),
-        jax.ShapeDtypeStruct((bsz, 1), jnp.float32),
-        jax.ShapeDtypeStruct((bsz, 1), jnp.float32),
+        jax.ShapeDtypeStruct((bsz,), jnp.float32),
+        jax.ShapeDtypeStruct((bsz,), jnp.float32),
     ]
     _launched()
     if bsz * m <= WHOLE_TILE_ROWS:
-        codes, mn, mx = pl.pallas_call(
+        return pl.pallas_call(
             functools.partial(_fused_encode_whole_kernel, bits=bits),
-            grid=(2,),
-            in_specs=[pl.BlockSpec((bsz, m, n), lambda p: (0, 0, 0))],
-            out_specs=[
-                pl.BlockSpec((bsz, m, out_n), lambda p: (0, 0, 0)),
-                pl.BlockSpec((bsz, 1), lambda p: (0, 0)),
-                pl.BlockSpec((bsz, 1), lambda p: (0, 0)),
-            ],
+            out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), _SMEM, _SMEM],
             out_shape=out_shape,
             interpret=interpret,
         )(x3d)
-        return codes, mn[:, 0], mx[:, 0]
     grid = (2, bsz, m // block_m)
-    codes, mn, mx = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_fused_encode_kernel, bits=bits),
         grid=grid,
         in_specs=[pl.BlockSpec((1, block_m, n), lambda p, b, i: (b, i, 0))],
         out_specs=[
             pl.BlockSpec((1, block_m, out_n),
                          lambda p, b, i: (p * b, p * i, 0)),
-            pl.BlockSpec((1, 1), lambda p, b, i: (b, 0)),
-            pl.BlockSpec((1, 1), lambda p, b, i: (b, 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_shape=out_shape,
-        scratch_shapes=[pltpu.SMEM((bsz, 2), jnp.float32)],
+        scratch_shapes=[pltpu.SMEM((2, bsz), jnp.float32)],
         interpret=interpret,
     )(x3d)
-    return codes, mn[:, 0], mx[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -228,19 +264,10 @@ def fused_encode_blocks(x3d: jnp.ndarray, bits: int, block_m: int,
 
 
 def _fused_decode_kernel(mn_ref, step_ref, q_ref, out_ref, *, packed: bool):
-    mn = mn_ref[0, 0]
-    step = step_ref[0, 0]
-    q = q_ref[...][0]
-    if packed:
-        lo = (q & 0x0F).astype(jnp.float32)
-        hi = (q >> 4).astype(jnp.float32)
-        # Interleave the two nibble streams back to lane order
-        # [lo0, hi0, ...] (the inverse of the pack's even/odd split).
-        m, half = q.shape
-        codes = jnp.stack([lo, hi], axis=-1).reshape(m, half * 2)
-    else:
-        codes = q.astype(jnp.float32)
-    out_ref[...] = ((codes * step + mn)[None]).astype(out_ref.dtype)
+    b = pl.program_id(0)
+    q = q_ref[0].astype(jnp.int32)
+    codes = _unpack_nibbles(q) if packed else q.astype(jnp.float32)
+    out_ref[0] = (codes * step_ref[b] + mn_ref[b]).astype(out_ref.dtype)
 
 
 def fused_decode_blocks(q3d: jnp.ndarray, mn, mx, bits: int, block_m: int,
@@ -250,13 +277,13 @@ def fused_decode_blocks(q3d: jnp.ndarray, mn, mx, bits: int, block_m: int,
 
     ``q3d`` is (B, M, W): one uint8/uint16 code per element, or two int4
     codes per byte (pack layout) when ``packed``. ``mn``/``mx`` are (B,)
-    per-sample scalars, routed to each grid step through a (1, 1) block —
-    the scalar-operand layout Pallas maps to SMEM.
+    per-sample scalars; ``mn`` and ``step`` enter as whole (B,) SMEM
+    operands that each grid step indexes by its sample id.
     """
     bsz, m, n = q3d.shape
     levels = float((1 << bits) - 1)
-    mn = jnp.reshape(mn.astype(jnp.float32), (bsz, 1))
-    mx = jnp.reshape(mx.astype(jnp.float32), (bsz, 1))
+    mn = jnp.reshape(mn.astype(jnp.float32), (bsz,))
+    mx = jnp.reshape(mx.astype(jnp.float32), (bsz,))
     step = jnp.where(levels > 0, (mx - mn) / levels, 0.0).astype(jnp.float32)
     out_n = n * 2 if packed else n
     grid = (bsz, m // block_m)
@@ -265,8 +292,8 @@ def fused_decode_blocks(q3d: jnp.ndarray, mn, mx, bits: int, block_m: int,
         functools.partial(_fused_decode_kernel, packed=packed),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
+            _SMEM,
+            _SMEM,
             pl.BlockSpec((1, block_m, n), lambda b, i: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_m, out_n), lambda b, i: (b, i, 0)),
@@ -283,39 +310,42 @@ def fused_decode_blocks(q3d: jnp.ndarray, mn, mx, bits: int, block_m: int,
 
 def pc_tiling(c: int, length: int, bits: int):
     """Static tile plan for the per-channel kernels: channels pad to a
-    sublane multiple and block ``cb`` at a time; the length axis packs in
-    ``chunk``-element blocks (a multiple of ``per_word * LANES`` so a
-    block of codes packs to whole 128-lane word rows). ``cb`` is sized to
-    keep one f32 tile under ``PC_TILE_BYTES``. Returns
-    (per_word, chunk, l_pad, c_pad, cb)."""
+    16-row multiple (the bf16 tile, so a bf16 decode output tiles too) and
+    block ``cb`` at a time; the length axis packs in ``chunk``-element
+    blocks (a multiple of ``per_word * LANES`` so a block of codes packs
+    to whole 128-lane word rows). ``cb`` is sized to keep one f32 tile
+    under ``PC_TILE_BYTES``. Returns (per_word, chunk, l_pad, c_pad, cb)."""
     per_word = 32 // bits
     base = per_word * LANES
     chunk = base * min(PC_CHUNK, max((length + base - 1) // base, 1))
     l_pad = max((length + chunk - 1) // chunk, 1) * chunk
-    c_pad = max((c + 7) // 8 * 8, 8)
-    cb = min(c_pad, max(8, PC_TILE_BYTES // (chunk * 4) // 8 * 8))
+    c_pad = max((c + 15) // 16 * 16, 16)
+    cb = min(c_pad, max(16, PC_TILE_BYTES // (chunk * 4) // 16 * 16))
     c_pad = (c_pad + cb - 1) // cb * cb
     return per_word, chunk, l_pad, c_pad, cb
 
 
 def _pc_encode_kernel(mn_ref, scale_ref, x_ref, out_ref,
-                      *, bits: int, per_word: int, n_per_ch: int,
-                      chunk: int):
+                      *, bits: int, per_word: int, n_per_ch: int):
+    """One (cb, wchunk) block of words: ``x_ref`` holds the block's codes
+    word-slot-major, (1, per_word, cb, wchunk), so slot k of every word
+    is one plain (cb, wchunk) tile."""
     i = pl.program_id(2)
-    mn = mn_ref[...][0][:, None]          # (cb, 1) per-channel vectors
-    scale = scale_ref[...][0][:, None]
-    blk = x_ref[...][0].astype(jnp.float32)    # (cb, chunk)
+    mn = mn_ref[0]                           # (cb, 1) per-channel columns
+    scale = scale_ref[0]
     levels = float((1 << bits) - 1)
-    q = jnp.clip(jnp.round((blk - mn) * scale), 0.0, levels)
-    # Zero the codes past the channel's true length so the final partial
-    # word matches a zero-padded reference pack bit-for-bit.
-    pos = i * chunk + jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
-    q = jnp.where(pos < n_per_ch, q, 0.0)
-    qi = q.astype(jnp.uint32)
-    w = qi[:, 0::per_word]
-    for k in range(1, per_word):
-        w = w | (qi[:, k::per_word] << (k * bits))
-    out_ref[...] = w[None]
+    cb, wchunk = out_ref.shape[1:]
+    word = i * wchunk + jax.lax.broadcasted_iota(jnp.int32, (cb, wchunk), 1)
+    w = None
+    for k in range(per_word):
+        blk = x_ref[0, k].astype(jnp.float32)
+        q = jnp.clip(jnp.round((blk - mn) * scale), 0.0, levels)
+        # Zero the codes past the channel's true length so the final
+        # partial word matches a zero-padded reference pack bit-for-bit.
+        q = jnp.where(word * per_word + k < n_per_ch, q, 0.0)
+        part = q.astype(jnp.int32).astype(jnp.uint32) << (k * bits)
+        w = part if w is None else w | part
+    out_ref[0] = w
 
 
 def pc_encode_blocks(xc: jnp.ndarray, mn2d: jnp.ndarray, mx2d: jnp.ndarray,
@@ -323,54 +353,55 @@ def pc_encode_blocks(xc: jnp.ndarray, mn2d: jnp.ndarray, mx2d: jnp.ndarray,
     """Fused per-channel quantize + c-bit pack, one launch.
 
     ``xc`` is (B, C, L) channel-major features; ``mn2d``/``mx2d`` are the
-    (B, C) per-channel range vectors, fed to the kernel as (cb,) vector
+    (B, C) per-channel range vectors, fed to the kernel as (cb, 1) column
     blocks. Returns (B, C, l_pad // per_word) uint32 words — ``32 //
     bits`` codes per word, codes never straddling a word, channels never
     sharing a word.
     """
     bsz, c, length = xc.shape
     per_word, chunk, l_pad, c_pad, cb = pc_tiling(c, length, bits)
+    w_pad = l_pad // per_word
     xc = jnp.pad(xc, ((0, 0), (0, c_pad - c), (0, l_pad - length)))
+    # Word-slot-major: code k of word j of channel ch -> [b, k, ch, j].
+    xs = xc.reshape(bsz, c_pad, w_pad, per_word).transpose(0, 3, 1, 2)
     levels = float((1 << bits) - 1)
     mn2d = mn2d.astype(jnp.float32)
     scale = jnp.where(mx2d > mn2d, levels / (mx2d - mn2d), 0.0)
     scale = jnp.pad(scale.astype(jnp.float32), ((0, 0), (0, c_pad - c)))
     mn2d = jnp.pad(mn2d, ((0, 0), (0, c_pad - c)))
-    grid = (bsz, c_pad // cb, l_pad // chunk)
+    wchunk = chunk // per_word
+    grid = (bsz, c_pad // cb, w_pad // wchunk)
     kernel = functools.partial(
-        _pc_encode_kernel, bits=bits, per_word=per_word,
-        n_per_ch=length, chunk=chunk,
+        _pc_encode_kernel, bits=bits, per_word=per_word, n_per_ch=length,
     )
+    col = pl.BlockSpec((1, cb, 1), lambda b, c_, i: (b, c_, 0))
     _launched()
     words = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, cb), lambda b, c_, i: (b, c_)),
-            pl.BlockSpec((1, cb), lambda b, c_, i: (b, c_)),
-            pl.BlockSpec((1, cb, chunk), lambda b, c_, i: (b, c_, i)),
+            col,
+            col,
+            pl.BlockSpec((1, per_word, cb, wchunk),
+                         lambda b, c_, i: (b, 0, c_, i)),
         ],
-        out_specs=pl.BlockSpec((1, cb, chunk // per_word),
-                               lambda b, c_, i: (b, c_, i)),
-        out_shape=jax.ShapeDtypeStruct(
-            (bsz, c_pad, l_pad // per_word), jnp.uint32
-        ),
+        out_specs=pl.BlockSpec((1, cb, wchunk), lambda b, c_, i: (b, c_, i)),
+        out_shape=jax.ShapeDtypeStruct((bsz, c_pad, w_pad), jnp.uint32),
         interpret=interpret,
-    )(mn2d, scale, xc)
+    )(mn2d[..., None], scale[..., None], xs)
     return words[:, :c]
 
 
 def _pc_decode_kernel(mn_ref, step_ref, w_ref, out_ref,
                       *, bits: int, per_word: int):
-    mn = mn_ref[...][0][:, None]
-    step = step_ref[...][0][:, None]
-    w = w_ref[...][0]                      # (cb, wchunk) uint32
+    mn = mn_ref[0]                           # (cb, 1)
+    step = step_ref[0]
+    w = w_ref[0]                             # (cb, wchunk) uint32
     mask = jnp.uint32((1 << bits) - 1)
-    parts = [((w >> (k * bits)) & mask).astype(jnp.float32)
-             for k in range(per_word)]
-    cb, wn = w.shape
-    codes = jnp.stack(parts, axis=-1).reshape(cb, wn * per_word)
-    out_ref[...] = ((codes * step + mn)[None]).astype(out_ref.dtype)
+    for k in range(per_word):
+        codes = ((w >> (k * bits)) & mask).astype(jnp.int32)
+        out_ref[0, k] = (codes.astype(jnp.float32) * step
+                         + mn).astype(out_ref.dtype)
 
 
 def pc_decode_blocks(w3d: jnp.ndarray, mn2d: jnp.ndarray, mx2d: jnp.ndarray,
@@ -380,7 +411,9 @@ def pc_decode_blocks(w3d: jnp.ndarray, mn2d: jnp.ndarray, mx2d: jnp.ndarray,
 
     Inverse of :func:`pc_encode_blocks`: (B, C, W) uint32 wire words ->
     (B, C, l_pad) dequantized activations in ``out_dtype`` (trailing axis
-    padded to the tile plan; callers trim to ``length``).
+    padded to the tile plan; callers trim to ``length``). The kernel
+    emits word-slot-major (B, per_word, C, W) tiles; the interleave back
+    to element order is an XLA transpose.
     """
     bsz, c, w_true = w3d.shape
     per_word, chunk, l_pad, c_pad, cb = pc_tiling(c, length, bits)
@@ -396,22 +429,24 @@ def pc_decode_blocks(w3d: jnp.ndarray, mn2d: jnp.ndarray, mx2d: jnp.ndarray,
     grid = (bsz, c_pad // cb, w_pad // wchunk)
     kernel = functools.partial(_pc_decode_kernel, bits=bits,
                                per_word=per_word)
+    col = pl.BlockSpec((1, cb, 1), lambda b, c_, i: (b, c_, 0))
     _launched()
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, cb), lambda b, c_, i: (b, c_)),
-            pl.BlockSpec((1, cb), lambda b, c_, i: (b, c_)),
+            col,
+            col,
             pl.BlockSpec((1, cb, wchunk), lambda b, c_, i: (b, c_, i)),
         ],
-        out_specs=pl.BlockSpec((1, cb, wchunk * per_word),
-                               lambda b, c_, i: (b, c_, i)),
+        out_specs=pl.BlockSpec((1, per_word, cb, wchunk),
+                               lambda b, c_, i: (b, 0, c_, i)),
         out_shape=jax.ShapeDtypeStruct(
-            (bsz, c_pad, l_pad), jnp.dtype(out_dtype)
+            (bsz, per_word, c_pad, w_pad), jnp.dtype(out_dtype)
         ),
         interpret=interpret,
-    )(mn2d, step, w3d)
+    )(mn2d[..., None], step[..., None], w3d)
+    out = out.transpose(0, 2, 3, 1).reshape(bsz, c_pad, l_pad)
     return out[:, :c]
 
 
@@ -422,9 +457,10 @@ def pc_decode_blocks(w3d: jnp.ndarray, mn2d: jnp.ndarray, mx2d: jnp.ndarray,
 
 
 def _minmax_kernel(x_ref, mn_ref, mx_ref):
+    i = pl.program_id(0)
     blk = x_ref[...].astype(jnp.float32)
-    mn_ref[0, 0] = jnp.min(blk)
-    mx_ref[0, 0] = jnp.max(blk)
+    mn_ref[i] = jnp.min(blk)
+    mx_ref[i] = jnp.max(blk)
 
 
 def minmax_blocks(x2d: jnp.ndarray, block_m: int, *, interpret: bool
@@ -436,13 +472,10 @@ def minmax_blocks(x2d: jnp.ndarray, block_m: int, *, interpret: bool
         _minmax_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block_m, n), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
+        out_specs=[_SMEM, _SMEM],
         out_shape=[
-            jax.ShapeDtypeStruct((grid[0], 1), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0], 1), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
+            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
         ],
         interpret=interpret,
     )(x2d)
@@ -452,11 +485,10 @@ def minmax_blocks(x2d: jnp.ndarray, block_m: int, *, interpret: bool
 def _quantize_kernel(mn_ref, scale_ref, x_ref, out_ref):
     mn = mn_ref[0]
     scale = scale_ref[0]
-    blk = x_ref[...].astype(jnp.float32)
-    q = jnp.round((blk - mn) * scale)
     levels = scale_ref[1]           # (2^c - 1), passed alongside the scale
-    q = jnp.clip(q, 0.0, levels)
-    out_ref[...] = q.astype(out_ref.dtype)
+    blk = x_ref[...].astype(jnp.float32)
+    q = jnp.clip(jnp.round((blk - mn) * scale), 0.0, levels)
+    out_ref[...] = q.astype(jnp.int32).astype(out_ref.dtype)
 
 
 def quantize_blocks(x2d, mn, mx, bits, block_m, *, interpret):
@@ -471,8 +503,8 @@ def quantize_blocks(x2d, mn, mx, bits, block_m, *, interpret):
         _quantize_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
+            _SMEM,
+            _SMEM,
             pl.BlockSpec((block_m, n), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_m, n), lambda i: (i, 0)),
@@ -482,10 +514,8 @@ def quantize_blocks(x2d, mn, mx, bits, block_m, *, interpret):
 
 
 def _pack4_kernel(q_ref, out_ref):
-    q = q_ref[...].astype(jnp.uint8)
-    lo = q[:, 0::2]
-    hi = q[:, 1::2]
-    out_ref[...] = (lo | (hi << 4)).astype(jnp.uint8)
+    q = q_ref[...].astype(jnp.int32).astype(jnp.float32)
+    out_ref[...] = _pack_lanes(q, 4, out_ref.dtype)
 
 
 def pack4_blocks(q2d: jnp.ndarray, block_m: int, *, interpret: bool

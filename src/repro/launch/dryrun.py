@@ -126,7 +126,7 @@ def dryrun_one(
         rules_mod.DEFAULT_RULES = rules
     try:
         step, args, in_sh = build_step(model, shape, train_cfg, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=in_sh).lower(*args)
             compiled = lowered.compile()
     finally:

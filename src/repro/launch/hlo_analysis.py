@@ -205,13 +205,9 @@ class RooflineReport:
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` returns a dict on jax>=0.4.30-ish and a
-    one-element list of dicts on earlier/other versions. Normalize to the
-    dict (empty if XLA produced no analysis)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict (empty if XLA produced no
+    analysis)."""
+    return compiled.cost_analysis() or {}
 
 
 def analyze_compiled(compiled, *, arch: str, shape: str, mesh_name: str,

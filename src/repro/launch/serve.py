@@ -22,6 +22,7 @@ from repro.config import JaladConfig, ServeConfig, get_config
 from repro.data.synthetic import make_batch
 from repro.models.api import build_model
 from repro.serving.engine import ServeSession
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.log import get_logger
 
 log = get_logger("repro.launch.serve")
@@ -168,6 +169,7 @@ def main(argv=None) -> int:
     ap.add_argument("--calib", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.jalad:
         return serve_jalad(args)
     return serve_lm(args)

@@ -27,8 +27,35 @@ class Model:
     specs: Any                                     # ParamSpec tree
 
     # ------------------------------------------------------------- params
-    def init(self, rng) -> Any:
-        return materialize(self.specs, rng)
+    def init(self, rng, shardings: Any = None) -> Any:
+        """Random parameters from ``rng``. With ``shardings`` (a
+        NamedSharding tree, e.g. ``serving.meshed.param_shardings``) every
+        leaf is created in its sharded layout, so no single device ever
+        holds the whole tree."""
+        if shardings is None:
+            return materialize(self.specs, rng)
+        return jax.jit(lambda r: materialize(self.specs, r),
+                       out_shardings=shardings)(rng)
+
+    def head_params(self, params, point: int) -> Any:
+        """The part of ``params`` that ``run_head(..., point)`` reads —
+        what the edge device holds: the layers up to the cut, without the
+        output head."""
+        cfg = self.cfg
+        if cfg.family == "cnn":
+            layers = cnn_lib.build_layers(cfg)[: point + 1]
+            return {lyr.name: params[lyr.name] for lyr in layers}
+        plan = tf_lib.segment_plan(cfg)
+        si, off = _point_to_segment(cfg, point)
+        segments = []
+        for sj, (seg, seg_params) in enumerate(zip(plan,
+                                                   params["segments"])):
+            count = seg.count if sj < si else off + 1 if sj == si else 0
+            segments.append(seg_params if seg.shared
+                            else _slice_seg(seg_params, 0, count))
+        out = {k: v for k, v in params.items() if k != "lm_head"}
+        out["segments"] = segments
+        return out
 
     def abstract_params(self) -> Any:
         return abstractify(self.specs)

@@ -74,7 +74,12 @@ class RunnerCache:
 
     ``mesh_worker`` (a :class:`~repro.serving.meshed.MeshedCloudWorker`)
     is threaded into every runner built here, so all cached plans share
-    ONE mesh + sharded param tree for their batched cloud steps."""
+    ONE mesh + sharded param tree for their cloud steps. The edge half
+    of such a runner reads only the head's slice of ``params``
+    (``Model.head_params``), copied once per cut to the default device:
+    ``params`` may then be created sharded over the mesh
+    (``Model.init(rng, param_shardings(model, mesh))``) and no device
+    ever holds the whole tree."""
 
     engine: JaladEngine
     params: Any
@@ -82,6 +87,7 @@ class RunnerCache:
     _cache: Dict[Tuple[int, int, str], DecoupledRunner] = field(
         default_factory=dict
     )
+    _heads: Dict[int, Any] = field(default_factory=dict, repr=False)
     _lock: Any = None
     _full_forward: Any = field(default=None, repr=False, compare=False)
 
@@ -108,11 +114,26 @@ class RunnerCache:
             # Build outside the lock: a miss (e.g. the adaptation listener
             # pre-registering a new plan) must not stall hits from the
             # other pipeline stages.
-            runner = self.engine.make_runner(self.params, plan,
-                                             mesh_worker=self.mesh_worker)
+            runner = self.engine.make_runner(
+                self.params, plan, mesh_worker=self.mesh_worker,
+                edge_params=self._edge_params(plan.point))
             with self._lock:
                 runner = self._cache.setdefault(key, runner)
         return runner
+
+    def _edge_params(self, point: int) -> Any:
+        if self.mesh_worker is None:
+            return None
+        head = self._heads.get(point)
+        if head is None:
+            import jax
+
+            head = jax.device_put(
+                self.engine.model.head_params(self.params, point),
+                jax.devices()[0])
+            with self._lock:
+                head = self._heads.setdefault(point, head)
+        return head
 
 
 @dataclass

@@ -168,7 +168,10 @@ class FleetServer:
     # (repro.serving.meshed.MeshedCloudWorker — float-equivalent to the
     # single-device tails, same contract as fuse_cloud_tail=True), and
     # the planner prices the cloud side under the matching
-    # CloudMeshModel, so plans genuinely shift as the mesh widens.
+    # CloudMeshModel, so plans genuinely shift as the mesh widens. The
+    # edge heads then read only Model.head_params on the default device,
+    # so ``params`` may be created sharded (Model.init(rng,
+    # param_shardings(model, mesh))) for a tail no single device holds.
     cloud_mesh: Optional[Any] = None
     # Planner-side per-remaining-layer collective seconds for the mesh
     # model (0.0 = ideal scaling; CloudMeshModel.from_interconnect prices

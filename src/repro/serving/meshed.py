@@ -10,13 +10,15 @@ MaxText-style SPMD runner:
   (config, mesh) through :func:`repro.sharding.rules.resolve_spec` (the
   priority-ordered, divisibility-checked rule table) and cached by config
   hash — like PR 5's calibration tables. ``params`` are ``device_put``
-  into those NamedShardings at worker construction, so every tail launch
-  reads weights already distributed over the mesh.
+  into those NamedShardings at worker construction (nothing moves when
+  they were created there, ``Model.init(rng, param_shardings(...))``),
+  so every tail launch reads weights already distributed over the mesh.
 
 * **Batch-sharded boundary entry.** A `(point, bits, codec)` group's wire
   blobs decode in ONE launch whose output is already sharded over the
-  "data" mesh axis (``kernels.quantize.ops.dequantize_wire_batch`` under
-  a sharded jit — no host gather, no replicated intermediate), and the
+  "data" mesh axis (``kernels.quantize.ops.dequantize_wire_batch`` in a
+  ``shard_map`` over that axis, since XLA cannot partition a Mosaic
+  kernel — no host gather, no replicated intermediate), and the
   decoded boundary is pinned via
   :func:`repro.sharding.activation.constrain` (batch on "data"; the rule
   table leaves seq/embed/spatial dims replicated so the params carry the
@@ -161,8 +163,14 @@ class MeshedCloudWorker:
             decode = (ops.dequantize_wire_batch_impl if wire == "bitpack"
                       else ops.dequantize_codes_batch_impl)
 
+            def decode_block(codes, mn, mx):
+                return decode(codes, mn, mx, bits, blob_shape,
+                              out_dtype=dtype)
+
+            decode_sharded = ops.shard_batch(decode_block, self.mesh)
+
             def fused(params, codes, mn, mx, extras):
-                x = decode(codes, mn, mx, bits, blob_shape, out_dtype=dtype)
+                x = decode_sharded(codes, mn, mx)
                 # Merge (n_blobs, b, ...) -> (n_blobs * b, ...): one tail
                 # forward over the whole group's samples.
                 x = x.reshape((-1,) + tuple(blob_shape[1:]))
@@ -259,7 +267,7 @@ class MeshedCloudWorker:
                                 tuple(blobs[0].shape), dtype, wire)
             args = self._put_batched((codes, mn, mx))
             extras = self._put_batched(extras)
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 logits = fn(self.params, *args, extras)
         else:
             boundaries = codec.decode_batch(blobs, out_dtype=dtype)
@@ -271,7 +279,7 @@ class MeshedCloudWorker:
             stacked = self._put_batched(stacked)
             extras = self._put_batched(extras)
             fn = self._tail_fn(point)
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 logits = fn(self.params, stacked, extras)
         self.fused_calls += 1
         self.group_sizes.append(total)
@@ -279,6 +287,19 @@ class MeshedCloudWorker:
         if len(counts) == 1:
             return [logits]
         return list(jnp.split(logits, np.cumsum(counts)[:-1], axis=0))
+
+    def cloud_step(self, blob: Any, extras: Any, plan) -> Any:
+        """One blob through the sharded tail, its boundary and extras
+        replicated over the mesh: the path for requests that
+        ``try_cloud_step_batch`` declines, since the tail's params live
+        only here."""
+        from repro.codec import get_codec
+
+        dtype = jnp.dtype(self.model.cfg.dtype)
+        x = get_codec(blob.codec).decode(blob, out_dtype=dtype)
+        x, extras = jax.device_put((x, extras), NamedSharding(self.mesh, P()))
+        with jax.set_mesh(self.mesh):
+            return self._tail_fn(int(plan.point))(self.params, x, extras)
 
 
 # ---------------------------------------------------------------------------
@@ -325,7 +346,7 @@ def aot_tail_report(model: Model, point: int, *, batch: int = 8,
         bshard = NamedSharding(
             mesh, P("data", *([None] * (len(boundary.shape) - 1))))
         eshard = jax.tree.map(lambda a: NamedSharding(mesh, P()), extras)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 tail, in_shardings=(pshard, bshard, eshard),
             ).lower(specs, boundary, extras)

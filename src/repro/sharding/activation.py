@@ -7,56 +7,25 @@ as a 14x FLOP blow-up in the olmo-1b dry-run). MaxText-style explicit
 ``with_sharding_constraint`` on the layer-boundary activations pins the
 intended layout.
 
-``constrain`` is a no-op outside a ``with mesh:`` context, so model code
-can call it unconditionally (CPU smoke tests see a single device and no
-mesh).
+``constrain`` is a no-op outside a ``with jax.set_mesh(mesh):`` context,
+so model code can call it unconditionally (CPU smoke tests see a single
+device and no mesh).
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AbstractMesh, NamedSharding
 
 from repro.sharding.rules import resolve_spec
 
 
-def _thread_resources():
-    """The jax thread-resources object holding the ambient mesh, via the
-    public surface first (versioned fallback chain):
-
-      1. ``jax.interpreters.pxla.thread_resources`` — the documented
-         re-export, stable across jax 0.3–0.5;
-      2. ``jax._src.mesh.thread_resources`` — the underlying internal,
-         for versions that drop the re-export.
-
-    Only missing-module/missing-attribute errors fall through; anything
-    else propagates. The old blanket ``except Exception`` silently
-    disabled every activation constraint whenever the internals moved —
-    the exact failure mode a sharding regression test cannot see.
-    """
-    try:
-        from jax.interpreters import pxla
-
-        return pxla.thread_resources
-    except (ImportError, AttributeError):
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-
-        return mesh_lib.thread_resources
-    except (ImportError, AttributeError):
-        return None
-
-
-def _ambient_mesh() -> Optional[Mesh]:
-    """The mesh of the innermost ``with mesh:`` block, or None."""
-    res = _thread_resources()
-    env = getattr(res, "env", None)
-    m = getattr(env, "physical_mesh", None)
-    if m is not None and not m.empty:
-        return m
-    return None
+def _ambient_mesh() -> Optional[AbstractMesh]:
+    """The mesh of the innermost ``with jax.set_mesh(mesh):`` block (as
+    the abstract mesh, which is also visible inside a trace), or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def constrain(x, logical: Sequence[Optional[str]]):
@@ -65,4 +34,7 @@ def constrain(x, logical: Sequence[Optional[str]]):
     if mesh is None:
         return x
     spec = resolve_spec(x.shape, logical, mesh)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    if not isinstance(x, jax.core.Tracer):
+        # Eager: the concrete mesh places the committed result.
+        spec = NamedSharding(jax.sharding.get_mesh(), spec)
+    return jax.lax.with_sharding_constraint(x, spec)

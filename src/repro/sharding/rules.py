@@ -77,7 +77,7 @@ def resolve_spec(
 ) -> P:
     """Resolve one array's PartitionSpec from its logical axes."""
     rules = rules if rules is not None else DEFAULT_RULES
-    axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    axis_sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     assignment: List[Optional[Tuple[str, ...]]] = [None] * len(shape)
     used: set = set()
     order = sorted(range(len(shape)), key=lambda i: _priority(logical[i]))

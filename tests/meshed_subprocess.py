@@ -22,6 +22,7 @@ from repro.kernels.quantize.ops import dequantize_wire_batch_sharded  # noqa: E4
 from repro.launch.mesh import make_host_mesh                     # noqa: E402
 from repro.serving.edge_cloud import build_edge_cloud_server     # noqa: E402
 from repro.serving.fleet import FleetRequest, FleetServer        # noqa: E402
+from repro.serving.meshed import param_shardings                 # noqa: E402
 from repro.sharding.activation import constrain                  # noqa: E402
 from repro.sharding.rules import resolve_spec                    # noqa: E402
 
@@ -31,12 +32,12 @@ BW = 3e5
 
 def check_constrain_regression(mesh):
     """Satellite: ``constrain`` must be a REAL constraint inside
-    ``with mesh:`` (committed NamedSharding over 8 devices, spec from the
-    rule table) and a strict no-op outside."""
+    ``with jax.set_mesh(mesh):`` (committed NamedSharding over 8
+    devices, spec from the rule table) and a strict no-op outside."""
     x = jnp.ones((16, 4, 8), jnp.float32)
     assert constrain(x, ("batch", "seq", "embed")) is x, \
         "constrain must be a no-op outside a mesh context"
-    with mesh:
+    with jax.set_mesh(mesh):
         y = constrain(x, ("batch", "seq", "embed"))
     assert y is not x
     want = resolve_spec(x.shape, ("batch", "seq", "embed"), mesh)
@@ -90,9 +91,20 @@ def check_fleet_e2e(arch, seq, mesh, codec_choices=("bitpack",)):
         cfg, jc, calib_batches=1, calib_batch_size=2, seq_len=seq)
     ref = FleetServer(srv.engine, params, PROFILES, fuse_cloud_tail=True)
     done_ref = ref.serve(_requests(cfg, seq))
-    meshed = FleetServer(srv.engine, params, PROFILES, cloud_mesh=mesh)
+    # The meshed fleet gets the same weights created already sharded
+    # (build_edge_cloud_server's init is Model.init(key(0))): no device
+    # ever holds the whole tree, and each edge head holds only its slice.
+    model = srv.engine.model
+    sharded = model.init(jax.random.key(0), param_shardings(model, mesh))
+    meshed = FleetServer(srv.engine, sharded, PROFILES, cloud_mesh=mesh)
     done_m = meshed.serve(_requests(cfg, seq))
     assert meshed.mesh_worker.fused_calls >= 1
+    assert all(a is b for a, b in zip(
+        jax.tree.leaves(meshed.mesh_worker.params),
+        jax.tree.leaves(sharded)))
+    for runner in meshed.runners._cache.values():
+        assert all(len(a.devices()) == 1
+                   for a in jax.tree.leaves(runner.edge_params))
     assert max(meshed.mesh_worker.group_sizes) >= 8, \
         meshed.mesh_worker.group_sizes
     by_r = {r.uid: r for r in done_ref}

@@ -2,7 +2,7 @@
 Huffman encode (``repro.kernels.entropy``).
 
 The contract: the two-phase device path (histogram dispatch + fused
-quantize/LUT-gather/scan/pack kernel) must reproduce the host reference
+quantize/LUT-gather/scan/pack program) must reproduce the host reference
 ``ent.huffman_encode`` byte-for-byte per sample, at every bit width the
 codec serves, in at most 2 device dispatches per batch — and must route
 to the host path (not emit a wrong stream) for trees it cannot pack.
@@ -18,7 +18,9 @@ from repro.core import entropy as ent
 from repro.core import quantization as q
 from repro.kernels.entropy import huffman_encode_batch_device
 from repro.kernels.entropy import ops as eops
-from repro.kernels.quantize import count_launches, dequantize_codes_batch
+from repro.kernels.quantize import (
+    count_host_routes, count_launches, dequantize_codes_batch,
+)
 
 BITS_SWEEP = (3, 5, 6, 8, 12, 16)      # uint16 codes included
 
@@ -49,14 +51,14 @@ def test_device_batch_byte_identical_to_host(bits):
 
 
 def test_multi_block_carry_byte_identical():
-    """Streams longer than one (block_m, 128) tile exercise the SMEM
-    bit-offset carry across grid blocks."""
+    """Long streams (many thousand u32 words per sample, past the
+    1024-word step of the output width) keep the per-sample bit offsets
+    and the sample rebasing exact."""
     xb = np.stack([
         np.random.default_rng(s).standard_normal(300_000).astype(np.float32)
         for s in range(2)
     ])
-    payloads, _, _ = huffman_encode_batch_device(
-        jnp.asarray(xb), 8, block_m=512)
+    payloads, _, _ = huffman_encode_batch_device(jnp.asarray(xb), 8)
     for b in range(2):
         assert payloads[b] == _reference(xb[b], 8)[0]
 
@@ -97,15 +99,22 @@ def test_empty_and_ragged_inputs_fall_back_cleanly():
         assert blob.payload == codec.encode(x, 6).payload
 
 
-def test_deep_tree_skew_byte_identical():
-    """Fibonacci frequencies force >13-bit codes (past the decoder's LUT
-    window) — the pack kernel's two-part emission must still match the
-    host bitstream exactly."""
+def _fibonacci_values(n: int = 24) -> np.ndarray:
+    """Symbol i repeated fib(i) times, shuffled: the skew that drives
+    Huffman code lengths deepest for a given element count."""
     fib = [1, 1]
-    while len(fib) < 24:
+    while len(fib) < n:
         fib.append(fib[-1] + fib[-2])
     vals = np.repeat(np.arange(len(fib)), fib).astype(np.float32)
     np.random.default_rng(3).shuffle(vals)
+    return vals
+
+
+def test_deep_tree_skew_byte_identical():
+    """Fibonacci frequencies force >13-bit codes (past the decoder's LUT
+    window) — the pack's two-part emission must still match the host
+    bitstream exactly."""
+    vals = _fibonacci_values()
     xb = np.stack([vals, vals[::-1].copy()])
     codes = np.asarray(q.quantize(jnp.asarray(xb[0]), 8).values)
     lens = ent._code_lengths(np.bincount(codes, minlength=256))
@@ -122,13 +131,29 @@ def test_overlong_codes_route_to_host_path(monkeypatch):
     (it needs Fibonacci skew over >5M elements), so the cap is lowered
     to pin the routing."""
     monkeypatch.setattr(eops, "PACK_MAX_CODE_BITS", 10)
-    fib = [1, 1]
-    while len(fib) < 24:
-        fib.append(fib[-1] + fib[-2])
-    vals = np.repeat(np.arange(len(fib)), fib).astype(np.float32)
+    vals = _fibonacci_values()
     assert huffman_encode_batch_device(jnp.asarray(vals)[None], 8) is None
     blob = get_codec("huffman").encode(jnp.asarray(vals), 8)
     assert blob.payload == _reference(vals, 8)[0]
+
+
+def test_host_routes_are_counted(monkeypatch):
+    """Every encode that leaves the device path for the host encoder is
+    counted: a deep-tree batch of three through the codec's batch call
+    is one rejected batch encode plus one per tensor, while device-path
+    and empty encodes count nothing."""
+    vals = jnp.asarray(_fibonacci_values())
+    codec = get_codec("huffman")
+    with count_host_routes() as c:
+        codec.encode_batch([vals, vals], 8)
+        huffman_encode_batch_device(jnp.zeros((0, 4)), 8)
+    assert c.count == 0
+    monkeypatch.setattr(eops, "PACK_MAX_CODE_BITS", 10)
+    with count_host_routes() as c:
+        blobs = codec.encode_batch([vals, vals, vals], 8)
+    assert c.count == 4
+    assert all(b.payload == _reference(np.asarray(vals), 8)[0]
+               for b in blobs)
 
 
 def test_launch_accounting_two_dispatches_per_batch():

@@ -35,7 +35,7 @@ def test_build_step_lowers(arch, mode):
     mesh = _mesh()
     step, args, in_sh = build_step(model, TINY_SHAPES[mode],
                                    TrainConfig(remat="blocks"), mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step, in_shardings=in_sh).lower(*args).compile()
     from repro.launch.hlo_analysis import cost_analysis_dict
 

@@ -168,3 +168,102 @@ def test_meshed_eight_device_subprocess():
     assert proc.returncode == 0, (
         f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr[-4000:]}")
     assert "ALL OK" in proc.stdout
+
+
+def test_sharded_init_creates_params_in_their_layout(served):
+    """``Model.init(rng, shardings)`` creates every leaf directly in the
+    worker's layout — a worker built on them has nothing left to move —
+    with the values of the plain init."""
+    srv, _, _ = served
+    model = srv.engine.model
+    mesh = _mesh1()
+    from repro.serving.meshed import param_shardings
+
+    shardings = param_shardings(model, mesh)
+    sharded = model.init(jax.random.key(3), shardings)
+    plain = model.init(jax.random.key(3))
+    for got, want, sh in zip(jax.tree.leaves(sharded),
+                             jax.tree.leaves(plain),
+                             jax.tree.leaves(shardings)):
+        assert got.sharding == sh
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=1e-6, atol=1e-7)
+    worker = MeshedCloudWorker(model, sharded, mesh)
+    assert all(a is b for a, b in zip(jax.tree.leaves(worker.params),
+                                      jax.tree.leaves(sharded)))
+
+
+@pytest.mark.parametrize("arch", ["granite-34b", "resnet50"])
+def test_head_params_hold_only_the_head(arch):
+    """The edge's slice of the params runs the head bit-identically and
+    holds no layer past the cut and no output head."""
+    from repro.models.api import build_model
+
+    cfg = get_config(arch).reduced()
+    model = build_model(cfg)
+    params = model.init(jax.random.key(0))
+    batch = make_batch(cfg, 2, 8, seed=1)
+    point = 0
+    head = model.head_params(params, point)
+    got = model.run_head(head, batch, point)
+    want = model.run_head(params, batch, point)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    n_head = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(head))
+    n_all = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
+    assert n_head < n_all
+    assert "lm_head" not in head
+
+
+def test_meshed_runner_cloud_step_runs_on_the_mesh(served):
+    """A meshed runner's single-blob cloud step (the path for groups the
+    worker cannot batch-shard) runs the worker's sharded tail, so an edge
+    holding only the head's slice still serves every request."""
+    srv, params, cfg = served
+    plan, runner, blobs, extras = _group(srv, params, cfg, n=2)
+    model = srv.engine.model
+    worker = MeshedCloudWorker(model, params, _mesh1())
+    meshed = srv.engine.make_runner(
+        params, plan, mesh_worker=worker,
+        edge_params=model.head_params(params, plan.point))
+    blob, e = meshed.edge_step(dict(make_batch(cfg, 1, 16, seed=40)))
+    assert blob.payload == blobs[0].payload
+    np.testing.assert_allclose(np.asarray(meshed.cloud_step(blob, e),
+                                          np.float32),
+                               np.asarray(runner.cloud_step(blob, e),
+                                          np.float32),
+                               rtol=RTOL, atol=ATOL)
+    assert worker.fused_calls == 0
+
+
+def test_meshed_fleet_edges_hold_only_the_head(served):
+    """``FleetServer(cloud_mesh=...)`` hands its edge runners only
+    ``Model.head_params`` and serves float-close to the single-device
+    fused tail."""
+    from repro.config.types import EDGE_TK1, EDGE_TX2
+    from repro.serving.fleet import FleetRequest, FleetServer
+
+    srv, params, cfg = served
+    profiles = [EDGE_TX2, EDGE_TK1]
+
+    def reqs():
+        return [FleetRequest(uid=i, device_id=i % 2,
+                             batch=dict(make_batch(cfg, 1, 16, seed=60 + i)),
+                             bandwidth=3e5) for i in range(4)]
+
+    ref = {r.uid: r for r in FleetServer(
+        srv.engine, params, profiles, fuse_cloud_tail=True).serve(reqs())}
+    fleet = FleetServer(srv.engine, params, profiles, cloud_mesh=_mesh1())
+    done = fleet.serve(reqs())
+    assert fleet.mesh_worker.fused_calls >= 1
+    for r in done:
+        np.testing.assert_allclose(np.asarray(r.logits, np.float32),
+                                   np.asarray(ref[r.uid].logits, np.float32),
+                                   rtol=RTOL, atol=ATOL)
+    decoupled = [r.plan for r in done if not r.plan.is_cloud_only]
+    assert decoupled
+    edge = fleet.runners.get(decoupled[0]).edge_params
+    assert "lm_head" not in edge
+    n_edge = sum(a.size for a in jax.tree.leaves(edge))
+    assert n_edge < sum(a.size for a in jax.tree.leaves(params))

@@ -165,8 +165,8 @@ def test_resolver_assignments_come_from_the_rule_table(logical, dims):
 
 
 def test_constrain_is_noop_outside_mesh():
-    """Outside any ``with mesh:`` scope constrain must return its input
-    unchanged (identity, not a copy) — eager edge-side code paths call it
+    """Outside any ``with jax.set_mesh(mesh):`` scope constrain must
+    return its input unchanged (identity, not a copy) — eager edge-side code paths call it
     unconditionally."""
     from repro.sharding.activation import constrain
 
@@ -191,7 +191,7 @@ def test_constrain_applies_inside_real_mesh():
     # The constraint must appear in the traced program inside the scope
     # (on one device the eager op returns its input, so the jaxpr is the
     # device-count-independent witness) — and stay absent outside it.
-    with mesh:
+    with jax.set_mesh(mesh):
         assert "sharding_constraint" in str(jax.make_jaxpr(fn)(x))
         np.testing.assert_array_equal(np.asarray(fn(x)), np.asarray(x))
     assert "sharding_constraint" not in str(jax.make_jaxpr(fn)(x))
@@ -205,5 +205,5 @@ def test_ambient_mesh_probe_uses_supported_import():
     assert _ambient_mesh() is None
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
                 ("data", "model"))
-    with mesh:
-        assert _ambient_mesh() is mesh
+    with jax.set_mesh(mesh):
+        assert _ambient_mesh() == mesh.abstract_mesh
