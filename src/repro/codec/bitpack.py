@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from typing import List, Sequence, Tuple
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -41,6 +42,7 @@ from repro.kernels.quantize import (
     quantize_pack,
     quantize_pack_stack,
 )
+from repro.utils.trace import span
 
 
 def _payload_bytes(n: int, bits: int) -> int:
@@ -73,7 +75,10 @@ class BitpackCodec(BoundaryCodec):
             return WireBlob(self.name, b"", shape, bits,
                             np.float32(0.0), np.float32(0.0))
         codes, mn, mx = quantize_pack(jnp.asarray(x), bits)
-        payload = _frame(np.asarray(codes).reshape(-1), n, bits)
+        with span("sync"):
+            codes, mn, mx = jax.device_get((codes, mn, mx))
+        with span("codec.frame"):
+            payload = _frame(codes.reshape(-1), n, bits)
         return WireBlob(self.name, payload, shape, bits,
                         np.float32(mn), np.float32(mx))
 
@@ -88,14 +93,17 @@ class BitpackCodec(BoundaryCodec):
         codes, mn, mx = quantize_pack_stack(
             tuple(jnp.asarray(x) for x in xs), bits
         )
-        flat = np.asarray(codes).reshape(len(xs), -1)
-        mn = np.asarray(mn, np.float32)
-        mx = np.asarray(mx, np.float32)
-        return [
-            WireBlob(self.name, _frame(flat[i], n, bits), shape, bits,
-                     mn[i], mx[i])
-            for i in range(len(xs))
-        ]
+        with span("sync"):
+            codes, mn, mx = jax.device_get((codes, mn, mx))
+        with span("codec.frame"):
+            flat = codes.reshape(len(xs), -1)
+            mn = np.asarray(mn, np.float32)
+            mx = np.asarray(mx, np.float32)
+            return [
+                WireBlob(self.name, _frame(flat[i], n, bits), shape, bits,
+                         mn[i], mx[i])
+                for i in range(len(xs))
+            ]
 
     def _wire_codes(self, blob: WireBlob) -> np.ndarray:
         if blob.bits <= 8:
@@ -105,9 +113,11 @@ class BitpackCodec(BoundaryCodec):
     def decode(self, blob: WireBlob, out_dtype=jnp.float32) -> jnp.ndarray:
         if blob.num_elements == 0:
             return jnp.zeros(blob.shape, out_dtype)
+        with span("codec.unframe"):
+            codes = jnp.asarray(self._wire_codes(blob))
         return dequantize_wire(
-            jnp.asarray(self._wire_codes(blob)), blob.x_min, blob.x_max,
-            blob.bits, blob.shape, out_dtype=out_dtype,
+            codes, blob.x_min, blob.x_max, blob.bits, blob.shape,
+            out_dtype=out_dtype,
         )
 
     def decode_batch(self, blobs: Sequence[WireBlob],
@@ -118,9 +128,11 @@ class BitpackCodec(BoundaryCodec):
                 or len({b.bits for b in blobs}) != 1):
             return [self.decode(b, out_dtype) for b in blobs]
         bits = blobs[0].bits
-        flat = jnp.asarray(np.stack([self._wire_codes(b) for b in blobs]))
-        mn = np.stack([np.float32(b.x_min) for b in blobs])
-        mx = np.stack([np.float32(b.x_max) for b in blobs])
+        with span("codec.unframe"):
+            flat = jnp.asarray(
+                np.stack([self._wire_codes(b) for b in blobs]))
+            mn = np.stack([np.float32(b.x_min) for b in blobs])
+            mx = np.stack([np.float32(b.x_max) for b in blobs])
         out = dequantize_wire_batch(flat, mn, mx, bits, blobs[0].shape,
                                     out_dtype=out_dtype)
         return [out[i] for i in range(len(blobs))]

@@ -34,6 +34,7 @@ from repro.codec.base import (
 )
 from repro.core import entropy as ent
 from repro.core import quantization as q
+from repro.utils.trace import span
 
 
 @functools.partial(jax.jit, static_argnames=("bits_list",))
@@ -60,11 +61,14 @@ class HuffmanCodec(BoundaryCodec):
         numpy bitstream build. The byte-identity oracle for the device
         path, and the route for deep-tree distributions it rejects."""
         quantized = q.quantize(jnp.asarray(x), bits)
-        codes = np.asarray(quantized.values)
-        payload = ent.huffman_encode(codes, 1 << bits)
+        with span("sync"):
+            codes, mn, mx = jax.device_get(
+                (quantized.values, quantized.x_min, quantized.x_max))
+        with span("codec.frame"):
+            payload = ent.huffman_encode(codes, 1 << bits)
         return WireBlob(
             self.name, payload, tuple(x.shape), bits,
-            np.float32(quantized.x_min), np.float32(quantized.x_max),
+            np.float32(mn), np.float32(mx),
         )
 
     def encode(self, x: jnp.ndarray, bits: int) -> WireBlob:
@@ -107,10 +111,11 @@ class HuffmanCodec(BoundaryCodec):
 
         # dequantize_codes narrows to the kernel's code dtype (uint8, or
         # uint16 for bits > 8) internally.
-        codes = ent.huffman_decode(blob.payload)
+        with span("codec.unframe"):
+            codes = jnp.asarray(
+                ent.huffman_decode(blob.payload).reshape(blob.shape))
         return dequantize_codes(
-            jnp.asarray(codes.reshape(blob.shape)),
-            blob.x_min, blob.x_max, blob.bits, blob.shape,
+            codes, blob.x_min, blob.x_max, blob.bits, blob.shape,
             out_dtype=out_dtype,
         )
 
@@ -125,12 +130,14 @@ class HuffmanCodec(BoundaryCodec):
 
         # Host entropy decode per payload (data-dependent lengths), then
         # ONE fused batched dequant+cast launch over the stacked codes.
-        codes = np.stack([ent.huffman_decode(b.payload) for b in blobs])
-        mn = np.stack([np.float32(b.x_min) for b in blobs])
-        mx = np.stack([np.float32(b.x_max) for b in blobs])
+        with span("codec.unframe"):
+            codes = jnp.asarray(
+                np.stack([ent.huffman_decode(b.payload) for b in blobs]))
+            mn = jnp.asarray(np.stack([np.float32(b.x_min) for b in blobs]))
+            mx = jnp.asarray(np.stack([np.float32(b.x_max) for b in blobs]))
         out = dequantize_codes_batch(
-            jnp.asarray(codes), jnp.asarray(mn), jnp.asarray(mx),
-            int(blobs[0].bits), shapes[0], out_dtype=out_dtype,
+            codes, mn, mx, int(blobs[0].bits), shapes[0],
+            out_dtype=out_dtype,
         )
         return [out[i] for i in range(len(blobs))]
 

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 from typing import List, Sequence, Tuple
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -46,6 +47,7 @@ from repro.kernels.quantize import (
     perchannel_encode_stack,
     perchannel_words,
 )
+from repro.utils.trace import span
 
 
 def channel_axis(ndim: int) -> int:
@@ -72,8 +74,10 @@ class PerChannelCodec(BoundaryCodec):
             return WireBlob(self.name, b"", shape, bits, zeros, zeros,
                             axis=ax)
         words, mn, mx = perchannel_encode(jnp.asarray(x), bits, ax)
-        payload = self._frame(np.asarray(words),
-                              int(x.size) // shape[ax], bits)
+        with span("sync"):
+            words, mn, mx = jax.device_get((words, mn, mx))
+        with span("codec.frame"):
+            payload = self._frame(words, int(x.size) // shape[ax], bits)
         return WireBlob(
             self.name, payload, shape, bits,
             np.asarray(mn, np.float32), np.asarray(mx, np.float32),
@@ -92,14 +96,16 @@ class PerChannelCodec(BoundaryCodec):
         words, mn, mx = perchannel_encode_stack(
             tuple(jnp.asarray(x) for x in xs), bits, ax
         )
-        words = np.asarray(words)
-        mn = np.asarray(mn, np.float32)
-        mx = np.asarray(mx, np.float32)
-        return [
-            WireBlob(self.name, self._frame(words[i], length, bits),
-                     shape, bits, mn[i], mx[i], axis=ax)
-            for i in range(len(xs))
-        ]
+        with span("sync"):
+            words, mn, mx = jax.device_get((words, mn, mx))
+        with span("codec.frame"):
+            mn = np.asarray(mn, np.float32)
+            mx = np.asarray(mx, np.float32)
+            return [
+                WireBlob(self.name, self._frame(words[i], length, bits),
+                         shape, bits, mn[i], mx[i], axis=ax)
+                for i in range(len(xs))
+            ]
 
     def _wire_words(self, blob: WireBlob) -> np.ndarray:
         c = blob.shape[blob.axis]
@@ -110,10 +116,12 @@ class PerChannelCodec(BoundaryCodec):
     def decode(self, blob: WireBlob, out_dtype=jnp.float32) -> jnp.ndarray:
         if blob.num_elements == 0:
             return jnp.zeros(blob.shape, out_dtype)
+        with span("codec.unframe"):
+            words = jnp.asarray(self._wire_words(blob))
+            mn, mx = jnp.asarray(blob.x_min), jnp.asarray(blob.x_max)
         return perchannel_decode(
-            jnp.asarray(self._wire_words(blob)),
-            jnp.asarray(blob.x_min), jnp.asarray(blob.x_max),
-            blob.bits, blob.shape, blob.axis, out_dtype=jnp.dtype(out_dtype),
+            words, mn, mx, blob.bits, blob.shape, blob.axis,
+            out_dtype=jnp.dtype(out_dtype),
         )
 
     def decode_batch(self, blobs: Sequence[WireBlob],
@@ -124,9 +132,11 @@ class PerChannelCodec(BoundaryCodec):
                 or len({b.bits for b in blobs}) != 1):
             return [self.decode(b, out_dtype) for b in blobs]
         first = blobs[0]
-        words = jnp.asarray(np.stack([self._wire_words(b) for b in blobs]))
-        mn = jnp.asarray(np.stack([b.x_min for b in blobs]))
-        mx = jnp.asarray(np.stack([b.x_max for b in blobs]))
+        with span("codec.unframe"):
+            words = jnp.asarray(
+                np.stack([self._wire_words(b) for b in blobs]))
+            mn = jnp.asarray(np.stack([b.x_min for b in blobs]))
+            mx = jnp.asarray(np.stack([b.x_max for b in blobs]))
         out = perchannel_decode_batch(
             words, mn, mx, first.bits, first.shape, first.axis,
             out_dtype=jnp.dtype(out_dtype),

@@ -25,6 +25,7 @@ from repro.core.predictor import PredictorTables
 from repro.core.tri_planner import TriPlanSpace
 from repro.core.quantization import quantize_dequantize
 from repro.models.api import Model
+from repro.utils.trace import span
 
 
 @dataclass
@@ -93,7 +94,8 @@ class DecoupledRunner:
     def edge_step(self, batch) -> Tuple["WireBlob", Any]:
         out = self._head(self.edge_params, batch, self.plan.point)
         boundary, extras = out if isinstance(out, tuple) else (out, None)
-        blob = self._codec.encode(boundary, self.plan.bits)
+        with span("codec.encode", rows=1):
+            blob = self._codec.encode(boundary, self.plan.bits)
         return blob, extras
 
     def edge_step_batch(self, batches) -> List[Tuple["WireBlob", Any]]:
@@ -104,8 +106,9 @@ class DecoupledRunner:
         outs = [self._head(self.edge_params, b, self.plan.point)
                 for b in batches]
         pairs = [o if isinstance(o, tuple) else (o, None) for o in outs]
-        blobs = self._codec.encode_batch([p[0] for p in pairs],
-                                         self.plan.bits)
+        with span("codec.encode", rows=len(pairs)):
+            blobs = self._codec.encode_batch([p[0] for p in pairs],
+                                             self.plan.bits)
         return [(blob, extras) for blob, (_, extras) in zip(blobs, pairs)]
 
     def cloud_step(self, blob: "WireBlob", extras=None):
@@ -114,7 +117,8 @@ class DecoupledRunner:
         if self.mesh_worker is not None:
             return self.mesh_worker.cloud_step(blob, extras, self.plan)
         dtype = jnp.dtype(self.model.cfg.dtype)
-        boundary = get_codec(blob.codec).decode(blob, out_dtype=dtype)
+        with span("codec.decode", rows=1):
+            boundary = get_codec(blob.codec).decode(blob, out_dtype=dtype)
         if extras is not None:
             return self._tail(self.params, boundary, self.plan.point, extras)
         return self._tail(self.params, boundary, self.plan.point)
@@ -149,6 +153,10 @@ class DecoupledRunner:
         position/encoder trees). Groups the worker cannot batch-shard
         (mixed codecs, non-stackable extras) go through it one blob at a
         time (``cloud_step``): the tail params live only on the mesh."""
+        with span("cloud", rows=len(blobs)):
+            return self._cloud_step_batch(blobs, extras_list, fuse_tail)
+
+    def _cloud_step_batch(self, blobs, extras_list, fuse_tail):
         from repro.codec import get_codec
 
         if extras_list is None:
@@ -172,8 +180,9 @@ class DecoupledRunner:
             return [self.cloud_step(b, e)
                     for b, e in zip(blobs, extras_list)]
         dtype = jnp.dtype(self.model.cfg.dtype)
-        boundaries = get_codec(blobs[0].codec).decode_batch(
-            blobs, out_dtype=dtype)
+        with span("codec.decode", rows=len(blobs)):
+            boundaries = get_codec(blobs[0].codec).decode_batch(
+                blobs, out_dtype=dtype)
         if not fuse_tail:
             return [self._tail(self.params, x, self.plan.point)
                     for x in boundaries]

@@ -45,6 +45,7 @@ import numpy as np
 from repro.core import entropy as ent
 from repro.kernels.entropy import huffman as hk
 from repro.kernels.quantize import quantize as k
+from repro.utils.trace import span
 
 LANES = k.LANES
 
@@ -206,9 +207,8 @@ def huffman_encode_batch_device(
     # ``count_launches`` reports dispatches, not pallas_calls only).
     k._launched()
     hist, mn_dev, mx, scale = _hist_ranges(xb.reshape(bsz, -1), bits)
-    hist = np.asarray(hist)
-    mn = np.asarray(mn_dev)
-    mx = np.asarray(mx)
+    with span("sync"):
+        hist, mn, mx = jax.device_get((hist, mn_dev, mx))
 
     tables = []
     for b in range(bsz):
@@ -249,18 +249,23 @@ def huffman_encode_batch_device(
     # Dispatch 2: the fused quantize + LUT gather + scan + pack program
     # (jitted — counted here, where every call really dispatches it).
     k._launched()
-    words = np.asarray(hk.huffman_pack(
+    words = hk.huffman_pack(
         xb.reshape(bsz, -1), mn_dev, scale, jnp.asarray(code_lut),
         jnp.asarray(len_lut), w_words=w_words, bits=bits, fold=fold,
         split_lut=split_lut,
-    ))
+    )
+    with span("sync"):
+        words = np.asarray(words)
 
     # Host framing only: header + big-endian word bytes trimmed to the
     # exact payload length (trailing bits are zero on both paths).
-    head = (np.uint32(n_elem).tobytes()
-            + np.uint16(num_symbols & 0xFFFF).tobytes())
-    payloads = []
-    for b, (_, _, lengths, total_bits) in enumerate(tables):
-        stream = words[b].astype(">u4").tobytes()[: (total_bits + 7) // 8]
-        payloads.append(head + lengths.astype(np.uint8).tobytes() + stream)
+    with span("codec.frame"):
+        head = (np.uint32(n_elem).tobytes()
+                + np.uint16(num_symbols & 0xFFFF).tobytes())
+        payloads = []
+        for b, (_, _, lengths, total_bits) in enumerate(tables):
+            stream = words[b].astype(">u4").tobytes()[
+                : (total_bits + 7) // 8]
+            payloads.append(
+                head + lengths.astype(np.uint8).tobytes() + stream)
     return payloads, mn, mx

@@ -240,6 +240,7 @@ def fused_encode_blocks(x3d: jnp.ndarray, bits: int, block_m: int,
             out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), _SMEM, _SMEM],
             out_shape=out_shape,
             interpret=interpret,
+            name="quantize_pack_whole",
         )(x3d)
     grid = (2, bsz, m // block_m)
     return pl.pallas_call(
@@ -255,6 +256,7 @@ def fused_encode_blocks(x3d: jnp.ndarray, bits: int, block_m: int,
         out_shape=out_shape,
         scratch_shapes=[pltpu.SMEM((2, bsz), jnp.float32)],
         interpret=interpret,
+        name="quantize_pack",
     )(x3d)
 
 
@@ -299,6 +301,7 @@ def fused_decode_blocks(q3d: jnp.ndarray, mn, mx, bits: int, block_m: int,
         out_specs=pl.BlockSpec((1, block_m, out_n), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, m, out_n), jnp.dtype(out_dtype)),
         interpret=interpret,
+        name="dequantize_wire",
     )(mn, step, q3d)
 
 
@@ -388,6 +391,7 @@ def pc_encode_blocks(xc: jnp.ndarray, mn2d: jnp.ndarray, mx2d: jnp.ndarray,
         out_specs=pl.BlockSpec((1, cb, wchunk), lambda b, c_, i: (b, c_, i)),
         out_shape=jax.ShapeDtypeStruct((bsz, c_pad, w_pad), jnp.uint32),
         interpret=interpret,
+        name="perchannel_encode",
     )(mn2d[..., None], scale[..., None], xs)
     return words[:, :c]
 
@@ -445,6 +449,7 @@ def pc_decode_blocks(w3d: jnp.ndarray, mn2d: jnp.ndarray, mx2d: jnp.ndarray,
             (bsz, per_word, c_pad, w_pad), jnp.dtype(out_dtype)
         ),
         interpret=interpret,
+        name="perchannel_decode",
     )(mn2d[..., None], step[..., None], w3d)
     out = out.transpose(0, 2, 3, 1).reshape(bsz, c_pad, l_pad)
     return out[:, :c]
@@ -478,6 +483,7 @@ def minmax_blocks(x2d: jnp.ndarray, block_m: int, *, interpret: bool
             jax.ShapeDtypeStruct((grid[0],), jnp.float32),
         ],
         interpret=interpret,
+        name="minmax",
     )(x2d)
     return jnp.min(mn), jnp.max(mx)
 
@@ -510,6 +516,7 @@ def quantize_blocks(x2d, mn, mx, bits, block_m, *, interpret):
         out_specs=pl.BlockSpec((block_m, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), code_dtype(bits)),
         interpret=interpret,
+        name="quantize",
     )(mn_arr, sc_arr, x2d)
 
 
@@ -530,4 +537,5 @@ def pack4_blocks(q2d: jnp.ndarray, block_m: int, *, interpret: bool
         out_specs=pl.BlockSpec((block_m, n // 2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n // 2), jnp.uint8),
         interpret=interpret,
+        name="pack4",
     )(q2d)

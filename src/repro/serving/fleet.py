@@ -56,6 +56,7 @@ from repro.core.latency import PNG_RATIO
 from repro.core.planner import FleetPlanSpace
 from repro.serving.edge_cloud import LatencyBreakdown, RunnerCache
 from repro.serving.pipeline import StageTimeline
+from repro.utils.trace import span
 
 PlanKey = Tuple[int, int, str]            # (point, bits, codec)
 
@@ -259,10 +260,12 @@ class FleetServer:
         (and therefore results) match serving each device alone."""
         for wave in self._waves(reqs):
             m = len(wave)
-            dv = np.fromiter((r.device_id for r in wave), np.int64, m)
-            bws = np.fromiter((r.bandwidth for r in wave), np.float64, m)
-            # ONE fused fleet re-decision for the whole wave.
-            plan_j, _ = self.controller.current_plans(bws, dv)
+            with span("fleet.plan", devices=m):
+                dv = np.fromiter((r.device_id for r in wave), np.int64, m)
+                bws = np.fromiter((r.bandwidth for r in wave), np.float64,
+                                  m)
+                # ONE fused fleet re-decision for the whole wave.
+                plan_j, _ = self.controller.current_plans(bws, dv)
             # Real numerics: per-request edge halves (heterogeneous plans
             # cannot batch across devices; PR 3's micro-batching still
             # applies inside each request's own batch).
@@ -274,43 +277,53 @@ class FleetServer:
                     nb = int(self.fleet_space.space.input_bytes * PNG_RATIO)
                 else:
                     runner = self.runners.get(plan)
-                    r._blob, r._extras = runner.edge_step(r.batch)
+                    with span("edge", uid=r.uid):
+                        r._blob, r._extras = runner.edge_step(r.batch)
                     nb = r._blob.nbytes
                 nbytes[i] = nb
-            # Array-backed simulated clocks: vectorized FIFO bookkeeping
-            # over the wave (each device appears at most once per wave).
-            edge_t, cloud_t = self.fleet_space.stage_times_all(plan_j, dv)
-            transfer_t = nbytes / bws
-            arrival = np.fromiter((r.arrival_s for r in wave),
-                                  np.float64, m)
-            edge_start = np.maximum(arrival, self._edge_free[dv])
-            edge_end = edge_start + edge_t
-            self._edge_free[dv] = edge_end
-            xfer_start = np.maximum(edge_end, self._link_free[dv])
-            xfer_end = xfer_start + transfer_t
-            self._link_free[dv] = xfer_end
-            self.controller.observe_transfers(
-                np.maximum(nbytes, 1), np.maximum(transfer_t, 1e-9), dv)
-            for i, r in enumerate(wave):
-                plan = r.plan
-                tl = r.timeline
-                tl.arrival_s = r.arrival_s
-                tl.edge_start = float(edge_start[i])
-                tl.edge_end = float(edge_end[i])
-                tl.xfer_start = float(xfer_start[i])
-                tl.xfer_end = float(xfer_end[i])
-                tl.bytes_sent = int(nbytes[i])
-                tl.plan_point = plan.point
-                tl.plan_bits = plan.bits
-                tl.plan_codec = (plan.codec if not plan.is_cloud_only
-                                 else "png")
-                r.breakdown = LatencyBreakdown(
-                    float(edge_t[i]), float(transfer_t[i]),
-                    float(cloud_t[i]), int(nbytes[i]),
-                    plan.point if not plan.is_cloud_only else -1,
-                    plan.bits if not plan.is_cloud_only else 0,
-                    plan.codec if not plan.is_cloud_only else "png",
-                )
+            with span("fleet.clocks"):
+                self._wave_clocks(wave, plan_j, dv, bws, nbytes)
+
+    def _wave_clocks(self, wave: List[FleetRequest], plan_j: np.ndarray,
+                     dv: np.ndarray, bws: np.ndarray,
+                     nbytes: np.ndarray) -> None:
+        """The wave's simulated edge and link clocks, their transfer
+        observations, and each request's timeline and breakdown."""
+        m = len(wave)
+        # Array-backed simulated clocks: vectorized FIFO bookkeeping
+        # over the wave (each device appears at most once per wave).
+        edge_t, cloud_t = self.fleet_space.stage_times_all(plan_j, dv)
+        transfer_t = nbytes / bws
+        arrival = np.fromiter((r.arrival_s for r in wave),
+                              np.float64, m)
+        edge_start = np.maximum(arrival, self._edge_free[dv])
+        edge_end = edge_start + edge_t
+        self._edge_free[dv] = edge_end
+        xfer_start = np.maximum(edge_end, self._link_free[dv])
+        xfer_end = xfer_start + transfer_t
+        self._link_free[dv] = xfer_end
+        self.controller.observe_transfers(
+            np.maximum(nbytes, 1), np.maximum(transfer_t, 1e-9), dv)
+        for i, r in enumerate(wave):
+            plan = r.plan
+            tl = r.timeline
+            tl.arrival_s = r.arrival_s
+            tl.edge_start = float(edge_start[i])
+            tl.edge_end = float(edge_end[i])
+            tl.xfer_start = float(xfer_start[i])
+            tl.xfer_end = float(xfer_end[i])
+            tl.bytes_sent = int(nbytes[i])
+            tl.plan_point = plan.point
+            tl.plan_bits = plan.bits
+            tl.plan_codec = (plan.codec if not plan.is_cloud_only
+                             else "png")
+            r.breakdown = LatencyBreakdown(
+                float(edge_t[i]), float(transfer_t[i]),
+                float(cloud_t[i]), int(nbytes[i]),
+                plan.point if not plan.is_cloud_only else -1,
+                plan.bits if not plan.is_cloud_only else 0,
+                plan.codec if not plan.is_cloud_only else "png",
+            )
 
     def _edge_and_link_phase_scalar(self, reqs: List[FleetRequest]) -> None:
         """Reference path (``vectorized=False``): the original per-device
@@ -359,11 +372,12 @@ class FleetServer:
         # Accounting: each request occupies the shared cloud stage for its
         # own modeled T_C, in arrival order — batching never changes the
         # reported numbers.
-        for r in queue:
-            tl = r.timeline
-            tl.cloud_start = max(tl.xfer_end, self._cloud_free)
-            tl.cloud_end = tl.cloud_start + r.breakdown.cloud_s
-            self._cloud_free = tl.cloud_end
+        with span("fleet.clocks"):
+            for r in queue:
+                tl = r.timeline
+                tl.cloud_start = max(tl.xfer_end, self._cloud_free)
+                tl.cloud_end = tl.cloud_start + r.breakdown.cloud_s
+                self._cloud_free = tl.cloud_end
         # Real numerics: group the in-flight queue by plan key and run one
         # batched wire decode + one batched tail forward per group.
         groups: Dict[Optional[PlanKey], List[FleetRequest]] = {}
@@ -409,17 +423,19 @@ class FleetServer:
             if not 0 <= r.device_id < self.n_devices:
                 raise ValueError(
                     f"request {r.uid} names unknown device {r.device_id}")
-        if self.vectorized:
-            self._edge_and_link_phase(reqs)
-        else:
-            self._edge_and_link_phase_scalar(reqs)
-        done = self._cloud_phase(reqs)
-        # Per-device bookkeeping in submission order — mirrors the
-        # synchronous server's clock/log exactly.
-        for r in reqs:
-            self._clock[r.device_id] += r.breakdown.total_s
-            self._logs[r.device_id].append(r.breakdown)
-            r._blob = r._extras = None
+        with span("fleet.serve", requests=len(reqs)):
+            if self.vectorized:
+                self._edge_and_link_phase(reqs)
+            else:
+                self._edge_and_link_phase_scalar(reqs)
+            done = self._cloud_phase(reqs)
+            # Per-device bookkeeping in submission order — mirrors the
+            # synchronous server's clock/log exactly.
+            with span("fleet.clocks"):
+                for r in reqs:
+                    self._clock[r.device_id] += r.breakdown.total_s
+                    self._logs[r.device_id].append(r.breakdown)
+                    r._blob = r._extras = None
         self.completed.extend(done)
         return done
 

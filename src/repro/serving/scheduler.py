@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.config.types import ServeConfig
 from repro.models.api import Model
+from repro.utils.trace import span
 
 
 @dataclass
@@ -94,9 +95,11 @@ class ContinuousBatchingEngine:
         buffers. The token-streaming session overrides this with split
         head/tail state (see :mod:`repro.serving.streaming`)."""
         L = self.cfg.max_seq_len
-        self._prefill = jax.jit(
-            lambda p, b: self.model.prefill(p, b, L)
-        )
+
+        def prefill(p, b):
+            return self.model.prefill(p, b, L)
+
+        self._prefill = jax.jit(prefill)
         self._decode = jax.jit(
             jax.vmap(self.model.decode_step, in_axes=(None, 0, 0, 0))
         )
@@ -111,7 +114,8 @@ class ContinuousBatchingEngine:
 
     # ------------------------------------------------------------ admission
     def submit(self, req: GenRequest) -> None:
-        self.queue.append(req)
+        with span("stream.submit", uid=req.uid):
+            self.queue.append(req)
 
     @property
     def num_active(self) -> int:
@@ -174,18 +178,20 @@ class ContinuousBatchingEngine:
         op, one host transfer. Returns (host tokens, device tokens).
         Compiles once per distinct active-slot count (bounded by
         ``max_batch``)."""
-        temps = np.array([self._slots[s].temperature for s in slots],
-                         np.float32)
-        keys = jnp.stack([
-            self._keys[s] if self._keys[s] is not None else self._dummy_key
-            for s in slots
-        ])
-        toks, new_keys = self._select(rows, keys, jnp.asarray(temps))
-        toks_np = np.asarray(toks)          # the step's single host sync
-        for j, s in enumerate(slots):
-            if temps[j] > 0:                # greedy slots never consume RNG
-                self._keys[s] = new_keys[j]
-        return toks_np, toks
+        with span("stream.select"):
+            temps = np.array([self._slots[s].temperature for s in slots],
+                             np.float32)
+            keys = jnp.stack([
+                self._keys[s] if self._keys[s] is not None
+                else self._dummy_key for s in slots
+            ])
+            toks, new_keys = self._select(rows, keys, jnp.asarray(temps))
+            with span("sync"):
+                toks_np = np.asarray(toks)  # the step's single host sync
+            for j, s in enumerate(slots):
+                if temps[j] > 0:            # greedy slots never consume RNG
+                    self._keys[s] = new_keys[j]
+            return toks_np, toks
 
     def _record_token(self, slot: int, token: int) -> None:
         req = self._slots[slot]

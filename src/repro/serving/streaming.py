@@ -53,6 +53,7 @@ from repro.codec import get_codec
 from repro.core.decoupler import DecoupledPlan
 from repro.models.api import Model
 from repro.serving.scheduler import ContinuousBatchingEngine, GenRequest
+from repro.utils.trace import span
 
 if TYPE_CHECKING:
     from repro.codec import BoundaryCodec, StreamHeader, WireBlob
@@ -106,17 +107,25 @@ class TokenStreamSession(ContinuousBatchingEngine):
         self.cloud_model = Model(cfg=cfg_cloud, specs=model.specs)
         self._codec: "BoundaryCodec" = get_codec(self.plan.codec)
         self._cloud_dtype = jnp.dtype(cfg_cloud.dtype)
-        self._prefill_head = jax.jit(
-            lambda p, b: model.prefill_head(p, b, L, point))
-        self._prefill_tail = jax.jit(
-            lambda p, x: self.cloud_model.prefill_tail(p, x, L, point))
-        self._decode_head = jax.jit(jax.vmap(
-            lambda p, t, pos, c: model.decode_head(p, t, pos, c, point, L),
-            in_axes=(None, 0, 0, 0)))
-        self._decode_tail = jax.jit(jax.vmap(
-            lambda p, x, pos, c: self.cloud_model.decode_tail(
-                p, x, pos, c, point, L),
-            in_axes=(None, 0, 0, 0)))
+
+        # Named, so that the trace reads jit_prefill_head and not a lambda.
+        def prefill_head(p, b):
+            return model.prefill_head(p, b, L, point)
+
+        def prefill_tail(p, x):
+            return self.cloud_model.prefill_tail(p, x, L, point)
+
+        def decode_head(p, t, pos, c):
+            return model.decode_head(p, t, pos, c, point, L)
+
+        def decode_tail(p, x, pos, c):
+            return self.cloud_model.decode_tail(p, x, pos, c, point, L)
+
+        slots = (None, 0, 0, 0)
+        self._prefill_head = jax.jit(prefill_head)
+        self._prefill_tail = jax.jit(prefill_tail)
+        self._decode_head = jax.jit(jax.vmap(decode_head, in_axes=slots))
+        self._decode_tail = jax.jit(jax.vmap(decode_tail, in_axes=slots))
         one_head = model.init_head_caches(1, L, point)
         one_tail = self.cloud_model.init_tail_caches(1, L, point)
         self._head_caches = self._stack_slots(one_head)
@@ -157,25 +166,32 @@ class TokenStreamSession(ContinuousBatchingEngine):
         """Prefill across the cut: head forward on the edge, the boundary
         sequence through the wire (real encode/decode round trip, counted
         at stream framing cost), tail prefill on the cloud."""
-        batch = {"tokens": jnp.asarray(req.tokens[None, :], jnp.int32)}
-        boundary, head = self._prefill_head(self.params, batch)
-        blob = self._codec.encode(boundary, self.plan.bits)
-        self.bytes_sent += blob.stream_nbytes
-        x = self._codec.decode(blob, out_dtype=self._cloud_dtype)
-        logits, tail = self._prefill_tail(self.params, x)
-        self._head_caches = jax.tree.map(
-            lambda buf, new: buf.at[slot].set(new), self._head_caches, head)
-        self._tail_caches = jax.tree.map(
-            lambda buf, new: buf.at[slot].set(new), self._tail_caches, tail)
-        self._pos = self._pos.at[slot].set(len(req.tokens))
-        req.slot = slot
-        req.joined_step = self.step_count
-        self._slots[slot] = req
-        self._keys[slot] = jax.random.key(self.cfg.seed + req.uid)
-        self.events.append(("join", self.step_count, req.uid))
-        toks_np, toks = self._select_tokens([slot], logits[:, -1])
-        self._last = self._last.at[slot, 0, 0].set(toks[0])
-        self._record_token(slot, int(toks_np[0]))
+        with span("stream.join", uid=req.uid, prompt_len=len(req.tokens)):
+            batch = {"tokens": jnp.asarray(req.tokens[None, :], jnp.int32)}
+            with span("stream.prefill_head"):
+                boundary, head = self._prefill_head(self.params, batch)
+            with span("codec.encode", rows=1):
+                blob = self._codec.encode(boundary, self.plan.bits)
+            self.bytes_sent += blob.stream_nbytes
+            with span("codec.decode", rows=1):
+                x = self._codec.decode(blob, out_dtype=self._cloud_dtype)
+            with span("stream.prefill_tail"):
+                logits, tail = self._prefill_tail(self.params, x)
+            self._head_caches = jax.tree.map(
+                lambda buf, new: buf.at[slot].set(new), self._head_caches,
+                head)
+            self._tail_caches = jax.tree.map(
+                lambda buf, new: buf.at[slot].set(new), self._tail_caches,
+                tail)
+            self._pos = self._pos.at[slot].set(len(req.tokens))
+            req.slot = slot
+            req.joined_step = self.step_count
+            self._slots[slot] = req
+            self._keys[slot] = jax.random.key(self.cfg.seed + req.uid)
+            self.events.append(("join", self.step_count, req.uid))
+            toks_np, toks = self._select_tokens([slot], logits[:, -1])
+            self._last = self._last.at[slot, 0, 0].set(toks[0])
+            self._record_token(slot, int(toks_np[0]))
 
     def _record_token(self, slot: int, token: int) -> None:
         self.tokens_out += 1
@@ -197,14 +213,15 @@ class TokenStreamSession(ContinuousBatchingEngine):
                     ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
         """Edge half of one step: ONE vmapped head decode over all slots,
         masked cache advance, gather the active boundary rows."""
-        boundary, new_head = self._decode_head(
-            self.params, self._last, self._pos, self._head_caches)
-        mask = np.zeros((self.cfg.max_batch,), bool)
-        mask[active] = True
-        mj = jnp.asarray(mask)
-        self._head_caches = self._masked_update(self._head_caches,
-                                                new_head, mj)
-        return [boundary[s] for s in active], mj
+        with span("stream.head", slots=len(active)):
+            boundary, new_head = self._decode_head(
+                self.params, self._last, self._pos, self._head_caches)
+            mask = np.zeros((self.cfg.max_batch,), bool)
+            mask[active] = True
+            mj = jnp.asarray(mask)
+            self._head_caches = self._masked_update(self._head_caches,
+                                                    new_head, mj)
+            return [boundary[s] for s in active], mj
 
     def _account_encode(self, active: List[int],
                         blobs: Sequence["WireBlob"]) -> List[int]:
@@ -218,22 +235,24 @@ class TokenStreamSession(ContinuousBatchingEngine):
         """Cloud half: scatter the decoded rows back to their slots, ONE
         vmapped tail decode (int8 KV update inside), masked advance.
         Returns the (k, V) logits rows of the active slots."""
-        n = self.cfg.max_batch
-        idx = jnp.asarray(active)
-        dec = jnp.zeros((n,) + self._frame_shape, self._cloud_dtype)
-        dec = dec.at[idx].set(jnp.stack(xs))
-        logits, new_tail = self._decode_tail(
-            self.params, dec, self._pos, self._tail_caches)
-        self._tail_caches = self._masked_update(self._tail_caches,
-                                                new_tail, mj)
-        self._pos = jnp.where(mj, self._pos + 1, self._pos)
-        return logits[idx, 0, -1]
+        with span("stream.tail", slots=len(active)):
+            n = self.cfg.max_batch
+            idx = jnp.asarray(active)
+            dec = jnp.zeros((n,) + self._frame_shape, self._cloud_dtype)
+            dec = dec.at[idx].set(jnp.stack(xs))
+            logits, new_tail = self._decode_tail(
+                self.params, dec, self._pos, self._tail_caches)
+            self._tail_caches = self._masked_update(self._tail_caches,
+                                                    new_tail, mj)
+            self._pos = jnp.where(mj, self._pos + 1, self._pos)
+            return logits[idx, 0, -1]
 
     def _finish_step(self, active: List[int], rows: jnp.ndarray) -> None:
         toks_np, toks = self._select_tokens(active, rows)
-        self._last = self._last.at[jnp.asarray(active), 0, 0].set(toks)
-        for j, slot in enumerate(active):
-            self._record_token(slot, int(toks_np[j]))
+        with span("stream.record", tokens=len(active)):
+            self._last = self._last.at[jnp.asarray(active), 0, 0].set(toks)
+            for j, slot in enumerate(active):
+                self._record_token(slot, int(toks_np[j]))
 
     # ------------------------------------------------------------------ step
     def step(self) -> List[GenRequest]:
@@ -243,17 +262,22 @@ class TokenStreamSession(ContinuousBatchingEngine):
         decode, vmapped tail decode,
         one batched token select + host sync. Returns the requests that
         finished during this step."""
-        self.step_count += 1
-        done_before = len(self.completed)
-        self._admit()
-        active = self._active_slots()
-        if active:
-            rows, mj = self._head_phase(active)
-            blobs = self._codec.encode_batch(rows, self.plan.bits)
-            self._account_encode(active, blobs)
-            xs = self._codec.decode_batch(blobs, out_dtype=self._cloud_dtype)
-            self._finish_step(active, self._tail_phase(active, mj, xs))
-        return self.completed[done_before:]
+        with span("stream.step"):
+            self.step_count += 1
+            done_before = len(self.completed)
+            with span("stream.admit"):
+                self._admit()
+            active = self._active_slots()
+            if active:
+                rows, mj = self._head_phase(active)
+                with span("codec.encode", rows=len(rows)):
+                    blobs = self._codec.encode_batch(rows, self.plan.bits)
+                self._account_encode(active, blobs)
+                with span("codec.decode", rows=len(blobs)):
+                    xs = self._codec.decode_batch(
+                        blobs, out_dtype=self._cloud_dtype)
+                self._finish_step(active, self._tail_phase(active, mj, xs))
+            return self.completed[done_before:]
 
     # ------------------------------------------------------------- protocol
     @property
@@ -306,9 +330,12 @@ def step_stream_group(sessions: Sequence[TokenStreamSession]
         rows, mj = s._head_phase(active) if active else ([], None)
         staged.append((s, active, rows, mj))
     all_rows = [r for _, _, rows, _ in staged for r in rows]
-    all_blobs = codec.encode_batch(all_rows, bits) if all_rows else []
-    all_xs = (codec.decode_batch(all_blobs, out_dtype=dtype)
-              if all_blobs else [])
+    all_blobs, all_xs = [], []
+    if all_rows:
+        with span("codec.encode", rows=len(all_rows)):
+            all_blobs = codec.encode_batch(all_rows, bits)
+        with span("codec.decode", rows=len(all_blobs)):
+            all_xs = codec.decode_batch(all_blobs, out_dtype=dtype)
     out: List[Tuple[TokenStreamSession, List[int]]] = []
     lo = 0
     for s, active, rows, mj in staged:

@@ -1,0 +1,24 @@
+"""Spans of the served paths, written into the profiler's own trace.
+
+``span(name, **stats)`` is a ``jax.profiler.TraceAnnotation`` named
+``jalad.<name>``. While a profiler trace runs (``jax.profiler.trace(dir)``
+or a client of ``jax.profiler.start_server(port)``) each span lands on
+its host thread, on the clock of the device timeline; spans nest by
+containment, and ``stats`` (small ints: ``uid``, ``slots``, ``rows``,
+``requests``) are the event's stats. With no trace running a span costs
+one to two microseconds of host time. The profiler is the only switch.
+
+A span named ``sync`` marks the host waiting for the device: every
+blocking device-to-host fetch on the served paths sits in one, and no
+other span has that name.
+"""
+from __future__ import annotations
+
+import jax
+
+PREFIX = "jalad."
+
+
+def span(name: str, **stats: int) -> jax.profiler.TraceAnnotation:
+    """The context manager of span ``jalad.<name>`` with ``stats``."""
+    return jax.profiler.TraceAnnotation(PREFIX + name, **stats)
