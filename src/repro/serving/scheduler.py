@@ -20,13 +20,22 @@ Token selection is batched the same way: greedy argmax and temperature
 sampling for **all** active slots run as one device computation per
 engine step (vmapped PRNG split + categorical, masked against each
 slot's temperature) followed by a single device->host transfer — not one
-``int(jnp.argmax(...))`` sync per slot per step. Each sampled slot still
-consumes exactly one split of its own per-request key per token, so
+``int(jnp.argmax(...))`` sync per slot per step. The per-request keys
+live on the device as one ``(max_batch,)`` key array that the select
+program gathers from and writes back; each sampled slot still consumes
+exactly one split of its own key per token, and greedy slots none, so
 sampled streams are identical to the per-slot path.
+
+Launches: a step runs a fixed handful of programs, whatever the number of
+live slots. The decode program takes the slot mask and the slot order as
+operands and does the masked cache advance, the position advance and the
+gather of the live logits rows inside, with the caches donated; the
+select and the token record are one program each. A join writes its slot
+and an eviction zeroes one with one donated program over the cache tree.
 
 Compile behaviour: the batched decode compiles once (fixed slot count and
 cache length). Prefill compiles per distinct prompt length, as in
-``ServeSession``.
+``ServeSession``; the select and the token record per live-slot count.
 """
 from __future__ import annotations
 
@@ -41,6 +50,23 @@ import numpy as np
 from repro.config.types import ServeConfig
 from repro.models.api import Model
 from repro.utils.trace import span
+
+
+def _write_slot(bufs: Any, new: Any, slot) -> Any:
+    """``bufs`` with slot ``slot`` of every leaf set to ``new``'s leaf."""
+    return jax.tree.map(lambda b, x: b.at[slot].set(x), bufs, new)
+
+
+def _zero_slot(bufs: Any, slot) -> Any:
+    """``bufs`` with slot ``slot`` of every leaf zeroed."""
+    return jax.tree.map(lambda b: b.at[slot].set(0), bufs)
+
+
+def _put_tokens(last: jnp.ndarray, idx: jnp.ndarray,
+                toks: jnp.ndarray) -> jnp.ndarray:
+    """``last`` with the first ``len(toks)`` slots of ``idx`` set to
+    ``toks``."""
+    return last.at[idx[:toks.shape[0]], 0, 0].set(toks)
 
 
 @dataclass
@@ -79,12 +105,15 @@ class ContinuousBatchingEngine:
                              "pipeline (repro.serving.pipeline)")
         n = self.cfg.max_batch
         self._init_compute()
-        self._select = jax.jit(self._batched_select)
-        self._dummy_key = jax.random.key(self.cfg.seed)
+        self._select = jax.jit(self._select_program, donate_argnums=1)
+        self._write_slot = jax.jit(_write_slot, donate_argnums=0)
+        self._zero_slot = jax.jit(_zero_slot, donate_argnums=0)
+        self._put_tokens = jax.jit(_put_tokens, donate_argnums=0)
         self._pos = jnp.zeros((n,), jnp.int32)
         self._last = jnp.zeros((n, 1, 1), jnp.int32)
         self._slots: List[Optional[GenRequest]] = [None] * n
-        self._keys = [None] * n                     # per-request PRNG state
+        # Per-request PRNG state, one key a slot; a join writes its own.
+        self._keys = jnp.stack([jax.random.key(self.cfg.seed)] * n)
         self.queue: Deque[GenRequest] = deque()
         self.completed: List[GenRequest] = []
         self.events: List[Tuple[str, int, int]] = []   # (kind, step, uid)
@@ -95,14 +124,18 @@ class ContinuousBatchingEngine:
         buffers. The token-streaming session overrides this with split
         head/tail state (see :mod:`repro.serving.streaming`)."""
         L = self.cfg.max_seq_len
+        step = jax.vmap(self.model.decode_step, in_axes=(None, 0, 0, 0))
 
         def prefill(p, b):
             return self.model.prefill(p, b, L)
 
+        def decode(p, last, pos, caches, mask, order):
+            logits, new = step(p, last, pos, caches)
+            return (self._masked_update(caches, new, mask),
+                    jnp.where(mask, pos + 1, pos), logits[order, 0, -1])
+
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(
-            jax.vmap(self.model.decode_step, in_axes=(None, 0, 0, 0))
-        )
+        self._decode = jax.jit(decode, donate_argnums=3)
         self._caches = self._stack_slots(self.model.init_caches(1, L, 0))
 
     def _stack_slots(self, one: Any) -> Any:
@@ -127,6 +160,17 @@ class ContinuousBatchingEngine:
     def _active_slots(self) -> List[int]:
         return [i for i, r in enumerate(self._slots) if r is not None]
 
+    def _slot_order(self, active: List[int]
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """A step's operands on the device: the (max_batch,) mask of the
+        active slots, and every slot index with the active ones first, in
+        order (the rows a step's programs gather and scatter)."""
+        mask = np.zeros((self.cfg.max_batch,), bool)
+        mask[active] = True
+        order = np.concatenate([np.asarray(active, np.int32),
+                                np.flatnonzero(~mask).astype(np.int32)])
+        return jax.device_put((mask, order))
+
     def _admit(self) -> None:
         """Admit eligible queued requests into free slots (FIFO; requests
         whose ``arrival`` lies in the future are deferred in order)."""
@@ -144,17 +188,23 @@ class ContinuousBatchingEngine:
     def _join(self, slot: int, req: GenRequest) -> None:
         batch = {"tokens": jnp.asarray(req.tokens[None, :], jnp.int32)}
         logits, caches = self._prefill(self.params, batch)
-        self._caches = jax.tree.map(
-            lambda buf, new: buf.at[slot].set(new), self._caches, caches
-        )
-        self._pos = self._pos.at[slot].set(len(req.tokens))
+        self._caches, self._pos = self._write_slot(
+            (self._caches, self._pos),
+            (caches, np.int32(len(req.tokens))), slot)
+        self._seat(slot, req, logits[:, -1])
+
+    def _seat(self, slot: int, req: GenRequest, rows: jnp.ndarray) -> None:
+        """Give ``req`` the slot its prefill was written to, and its first
+        token from the prefill's last logits row ``rows`` (1, V)."""
         req.slot = slot
         req.joined_step = self.step_count
         self._slots[slot] = req
-        self._keys[slot] = jax.random.key(self.cfg.seed + req.uid)
+        self._keys = self._write_slot(
+            self._keys, jax.random.key(self.cfg.seed + req.uid), slot)
         self.events.append(("join", self.step_count, req.uid))
-        toks_np, toks = self._select_tokens([slot], logits[:, -1])
-        self._last = self._last.at[slot, 0, 0].set(toks[0])
+        toks_np, toks = self._select_tokens([slot], rows)
+        self._last = self._put_tokens(self._last,
+                                      np.asarray([slot], np.int32), toks)
         self._record_token(slot, int(toks_np[0]))
 
     @staticmethod
@@ -172,6 +222,15 @@ class ContinuousBatchingEngine:
         ).astype(jnp.int32)
         return jnp.where(temps > 0, sampled, greedy), new_keys
 
+    def _select_program(self, rows: jnp.ndarray, keys, idx: jnp.ndarray,
+                        temps: jnp.ndarray):
+        """The select over the slots ``idx`` (k,) of the (max_batch,)
+        ``keys``: their tokens, and ``keys`` with each sampled slot's key
+        advanced (greedy slots never consume RNG)."""
+        mine = keys[idx]
+        toks, new = self._batched_select(rows, mine, temps)
+        return toks, keys.at[idx].set(jnp.where(temps > 0, new, mine))
+
     def _select_tokens(self, slots: List[int], rows: jnp.ndarray
                        ) -> Tuple[np.ndarray, jnp.ndarray]:
         """Select the next token for every listed slot: one batched device
@@ -181,17 +240,22 @@ class ContinuousBatchingEngine:
         with span("stream.select"):
             temps = np.array([self._slots[s].temperature for s in slots],
                              np.float32)
-            keys = jnp.stack([
-                self._keys[s] if self._keys[s] is not None
-                else self._dummy_key for s in slots
-            ])
-            toks, new_keys = self._select(rows, keys, jnp.asarray(temps))
+            toks, self._keys = self._select(
+                rows, self._keys, np.asarray(slots, np.int32), temps)
             with span("sync"):
                 toks_np = np.asarray(toks)  # the step's single host sync
-            for j, s in enumerate(slots):
-                if temps[j] > 0:            # greedy slots never consume RNG
-                    self._keys[s] = new_keys[j]
             return toks_np, toks
+
+    def _finish_step(self, active: List[int], order: jnp.ndarray,
+                     rows: jnp.ndarray) -> None:
+        """Select the active slots' tokens from their logits ``rows`` (row
+        j is ``active[j]``'s), feed them back as the next step's input and
+        record them."""
+        toks_np, toks = self._select_tokens(active, rows)
+        with span("stream.record", tokens=len(active)):
+            self._last = self._put_tokens(self._last, order, toks)
+            for j, slot in enumerate(active):
+                self._record_token(slot, int(toks_np[j]))
 
     def _record_token(self, slot: int, token: int) -> None:
         req = self._slots[slot]
@@ -216,7 +280,6 @@ class ContinuousBatchingEngine:
         req = self._slots[slot]
         req.done_step = self.step_count
         self._slots[slot] = None
-        self._keys[slot] = None
         self.completed.append(req)
         self.events.append(("evict", self.step_count, req.uid))
 
@@ -230,23 +293,13 @@ class ContinuousBatchingEngine:
         self._admit()
         active = self._active_slots()
         if active:
-            logits, new_caches = self._decode(
-                self.params, self._last, self._pos, self._caches
-            )
             # Only active slots advance; free slots keep their (ignored)
             # state until a join overwrites it.
-            mask = np.zeros((self.cfg.max_batch,), bool)
-            mask[active] = True
-            mj = jnp.asarray(mask)
-            self._caches = self._masked_update(self._caches, new_caches, mj)
-            self._pos = jnp.where(mj, self._pos + 1, self._pos)
-            # One batched select + one host transfer for all active slots
-            # (the old path synced the host once per slot per step).
-            rows = logits[jnp.asarray(active), 0, -1]
-            toks_np, toks = self._select_tokens(active, rows)
-            self._last = self._last.at[jnp.asarray(active), 0, 0].set(toks)
-            for j, slot in enumerate(active):
-                self._record_token(slot, int(toks_np[j]))
+            mask, order = self._slot_order(active)
+            self._caches, self._pos, rows = self._decode(
+                self.params, self._last, self._pos, self._caches, mask,
+                order)
+            self._finish_step(active, order, rows[:len(active)])
         return self.completed[done_before:]
 
     def run(self) -> List[GenRequest]:

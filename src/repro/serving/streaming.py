@@ -22,6 +22,15 @@ batching engine so the decode loop itself runs across the cut:
   in one vmapped decode. Token selection keeps the scheduler's single
   host-sync-per-step property; the wire adds exactly one more host
   round-trip per step, never one per slot.
+* **One donated program per side.** Each side of the cut is one jitted
+  program over the whole stacked slot state, its caches donated: the
+  head program returns the advanced caches and every slot's boundary row
+  as its own output (the host picks the live ones with no launch); the
+  tail program scatters the decoded rows into their slots, decodes, and
+  does the masked cache advance, the position advance and the gather of
+  the live logits rows. Both take the slot mask and order as operands,
+  so they compile once per session and the step launches the same
+  handful of programs whatever the number of live slots.
 * **Streaming wire format.** A per-session
   :class:`~repro.codec.base.StreamHeader` pins (codec, bits, frame
   shape) once at session open, so every subsequent frame costs
@@ -115,22 +124,39 @@ class TokenStreamSession(ContinuousBatchingEngine):
         def prefill_tail(p, x):
             return self.cloud_model.prefill_tail(p, x, L, point)
 
-        def decode_head(p, t, pos, c):
-            return model.decode_head(p, t, pos, c, point, L)
-
-        def decode_tail(p, x, pos, c):
-            return self.cloud_model.decode_tail(p, x, pos, c, point, L)
-
         slots = (None, 0, 0, 0)
+        head = jax.vmap(
+            lambda p, t, pos, c: model.decode_head(p, t, pos, c, point, L),
+            in_axes=slots)
+        tail = jax.vmap(
+            lambda p, x, pos, c: self.cloud_model.decode_tail(
+                p, x, pos, c, point, L),
+            in_axes=slots)
+        n = self.cfg.max_batch
+        self._frame_shape = (1, 1, int(model.cfg.d_model))
+
+        def decode_head(p, last, pos, caches, mask):
+            boundary, new = head(p, last, pos, caches)
+            return (self._masked_update(caches, new, mask),
+                    tuple(boundary[i] for i in range(n)))
+
+        def decode_tail(p, rows, order, pos, caches, mask):
+            x = jnp.zeros((n,) + self._frame_shape, self._cloud_dtype)
+            x = x.at[order].set(jnp.stack(rows))
+            logits, new = tail(p, x, pos, caches)
+            return (self._masked_update(caches, new, mask),
+                    jnp.where(mask, pos + 1, pos), logits[order, 0, -1])
+
         self._prefill_head = jax.jit(prefill_head)
         self._prefill_tail = jax.jit(prefill_tail)
-        self._decode_head = jax.jit(jax.vmap(decode_head, in_axes=slots))
-        self._decode_tail = jax.jit(jax.vmap(decode_tail, in_axes=slots))
+        self._decode_head = jax.jit(decode_head, donate_argnums=3)
+        self._decode_tail = jax.jit(decode_tail, donate_argnums=4)
         one_head = model.init_head_caches(1, L, point)
         one_tail = self.cloud_model.init_tail_caches(1, L, point)
         self._head_caches = self._stack_slots(one_head)
         self._tail_caches = self._stack_slots(one_tail)
-        self._frame_shape = (1, 1, int(model.cfg.d_model))
+        # What the tail program reads for a slot with no row this step.
+        self._no_row = jnp.zeros(self._frame_shape, self._cloud_dtype)
         # Session-open handshake: (codec, bits, frame shape) ship once,
         # every frame after that costs stream_nbytes.
         self.header: "StreamHeader" = self._codec.open_stream(
@@ -177,21 +203,12 @@ class TokenStreamSession(ContinuousBatchingEngine):
                 x = self._codec.decode(blob, out_dtype=self._cloud_dtype)
             with span("stream.prefill_tail"):
                 logits, tail = self._prefill_tail(self.params, x)
-            self._head_caches = jax.tree.map(
-                lambda buf, new: buf.at[slot].set(new), self._head_caches,
-                head)
-            self._tail_caches = jax.tree.map(
-                lambda buf, new: buf.at[slot].set(new), self._tail_caches,
-                tail)
-            self._pos = self._pos.at[slot].set(len(req.tokens))
-            req.slot = slot
-            req.joined_step = self.step_count
-            self._slots[slot] = req
-            self._keys[slot] = jax.random.key(self.cfg.seed + req.uid)
-            self.events.append(("join", self.step_count, req.uid))
-            toks_np, toks = self._select_tokens([slot], logits[:, -1])
-            self._last = self._last.at[slot, 0, 0].set(toks[0])
-            self._record_token(slot, int(toks_np[0]))
+            self._head_caches = self._write_slot(self._head_caches, head,
+                                                 slot)
+            self._tail_caches, self._pos = self._write_slot(
+                (self._tail_caches, self._pos),
+                (tail, np.int32(len(req.tokens))), slot)
+            self._seat(slot, req, logits[:, -1])
 
     def _record_token(self, slot: int, token: int) -> None:
         self.tokens_out += 1
@@ -203,25 +220,20 @@ class TokenStreamSession(ContinuousBatchingEngine):
         # buffers are zeroed, and since eviction removes the slot from
         # the active set, the request can never appear in a later
         # batched encode group (asserted in tests).
-        self._head_caches = jax.tree.map(
-            lambda a: a.at[slot].set(0), self._head_caches)
-        self._tail_caches = jax.tree.map(
-            lambda a: a.at[slot].set(0), self._tail_caches)
+        self._head_caches = self._zero_slot(self._head_caches, slot)
+        self._tail_caches = self._zero_slot(self._tail_caches, slot)
 
     # --------------------------------------------------------- step phases
-    def _head_phase(self, active: List[int]
-                    ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
-        """Edge half of one step: ONE vmapped head decode over all slots,
-        masked cache advance, gather the active boundary rows."""
+    def _head_phase(self, active: List[int]) -> Tuple[
+            List[jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]]:
+        """Edge half of one step: ONE head program over all slots (vmapped
+        decode, masked cache advance). Returns the active slots' boundary
+        rows and the step's (mask, order) operands."""
         with span("stream.head", slots=len(active)):
-            boundary, new_head = self._decode_head(
-                self.params, self._last, self._pos, self._head_caches)
-            mask = np.zeros((self.cfg.max_batch,), bool)
-            mask[active] = True
-            mj = jnp.asarray(mask)
-            self._head_caches = self._masked_update(self._head_caches,
-                                                    new_head, mj)
-            return [boundary[s] for s in active], mj
+            mask, order = self._slot_order(active)
+            self._head_caches, boundary = self._decode_head(
+                self.params, self._last, self._pos, self._head_caches, mask)
+            return [boundary[s] for s in active], (mask, order)
 
     def _account_encode(self, active: List[int],
                         blobs: Sequence["WireBlob"]) -> List[int]:
@@ -230,29 +242,21 @@ class TokenStreamSession(ContinuousBatchingEngine):
         self.bytes_sent += sum(b.stream_nbytes for b in blobs)
         return uids
 
-    def _tail_phase(self, active: List[int], mj: jnp.ndarray,
+    def _tail_phase(self, active: List[int],
+                    sel: Tuple[jnp.ndarray, jnp.ndarray],
                     xs: Sequence[jnp.ndarray]) -> jnp.ndarray:
-        """Cloud half: scatter the decoded rows back to their slots, ONE
-        vmapped tail decode (int8 KV update inside), masked advance.
-        Returns the (k, V) logits rows of the active slots."""
+        """Cloud half: ONE tail program over all slots (the decoded rows
+        scattered to their slots, vmapped decode with the int8 KV update
+        inside, masked advance). Returns the (k, V) logits rows of the
+        active slots."""
+        mask, order = sel
         with span("stream.tail", slots=len(active)):
-            n = self.cfg.max_batch
-            idx = jnp.asarray(active)
-            dec = jnp.zeros((n,) + self._frame_shape, self._cloud_dtype)
-            dec = dec.at[idx].set(jnp.stack(xs))
-            logits, new_tail = self._decode_tail(
-                self.params, dec, self._pos, self._tail_caches)
-            self._tail_caches = self._masked_update(self._tail_caches,
-                                                    new_tail, mj)
-            self._pos = jnp.where(mj, self._pos + 1, self._pos)
-            return logits[idx, 0, -1]
-
-    def _finish_step(self, active: List[int], rows: jnp.ndarray) -> None:
-        toks_np, toks = self._select_tokens(active, rows)
-        with span("stream.record", tokens=len(active)):
-            self._last = self._last.at[jnp.asarray(active), 0, 0].set(toks)
-            for j, slot in enumerate(active):
-                self._record_token(slot, int(toks_np[j]))
+            rows = tuple(xs) + (self._no_row,) * (self.cfg.max_batch
+                                                  - len(xs))
+            self._tail_caches, self._pos, logits = self._decode_tail(
+                self.params, rows, order, self._pos, self._tail_caches,
+                mask)
+            return logits[:len(active)]
 
     # ------------------------------------------------------------------ step
     def step(self) -> List[GenRequest]:
@@ -269,14 +273,15 @@ class TokenStreamSession(ContinuousBatchingEngine):
                 self._admit()
             active = self._active_slots()
             if active:
-                rows, mj = self._head_phase(active)
+                rows, sel = self._head_phase(active)
                 with span("codec.encode", rows=len(rows)):
                     blobs = self._codec.encode_batch(rows, self.plan.bits)
                 self._account_encode(active, blobs)
                 with span("codec.decode", rows=len(blobs)):
                     xs = self._codec.decode_batch(
                         blobs, out_dtype=self._cloud_dtype)
-                self._finish_step(active, self._tail_phase(active, mj, xs))
+                self._finish_step(active, sel[1],
+                                  self._tail_phase(active, sel, xs))
             return self.completed[done_before:]
 
     # ------------------------------------------------------------- protocol
@@ -327,8 +332,8 @@ def step_stream_group(sessions: Sequence[TokenStreamSession]
         s.step_count += 1
         s._admit()
         active = s._active_slots()
-        rows, mj = s._head_phase(active) if active else ([], None)
-        staged.append((s, active, rows, mj))
+        rows, sel = s._head_phase(active) if active else ([], None)
+        staged.append((s, active, rows, sel))
     all_rows = [r for _, _, rows, _ in staged for r in rows]
     all_blobs, all_xs = [], []
     if all_rows:
@@ -338,14 +343,14 @@ def step_stream_group(sessions: Sequence[TokenStreamSession]
             all_xs = codec.decode_batch(all_blobs, out_dtype=dtype)
     out: List[Tuple[TokenStreamSession, List[int]]] = []
     lo = 0
-    for s, active, rows, mj in staged:
+    for s, active, rows, sel in staged:
         hi = lo + len(rows)
         blobs, xs = all_blobs[lo:hi], all_xs[lo:hi]
         lo = hi
         uids: List[int] = []
         if active:
             uids = s._account_encode(active, blobs)
-            s._finish_step(active, s._tail_phase(active, mj, xs))
+            s._finish_step(active, sel[1], s._tail_phase(active, sel, xs))
         out.append((s, uids))
     return out
 

@@ -1,6 +1,7 @@
 """Pipelined serving subsystem: continuous-batching join/evict semantics,
 overlap correctness (pipelined numerics == synchronous numerics), and
 live re-decoupling on a bandwidth step-change."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -112,6 +113,40 @@ def test_continuous_output_matches_synchronous_batch1():
             {"tokens": jnp.asarray(r.tokens[None, :])}, r.max_new_tokens
         )[0]
         np.testing.assert_array_equal(r.result, np.asarray(ref))
+
+
+def test_sampled_output_follows_each_request_key():
+    """Batched sampling draws each request's tokens from its own key
+    (``seed + uid``), one split per token, whatever the other slots do;
+    a greedy request beside them consumes none. The reference serves each
+    request alone through ServeSession's programs and samples on the
+    host's side of the loop."""
+    eng, model, params = _make_engine(max_batch=3, max_seq_len=48)
+    temps = [0.8, 0.0, 1.3]
+    prompts = _prompts(model.cfg, [5, 7, 6], seed=4)
+    for i, t in enumerate(temps):
+        eng.submit(GenRequest(uid=i, tokens=prompts[i], max_new_tokens=6,
+                              temperature=t))
+    done = {r.uid: r.result for r in eng.run()}
+
+    session = ServeSession(model, params,
+                           ServeConfig(max_batch=3, max_seq_len=48))
+    for i, t in enumerate(temps):
+        logits, caches = session._prefill(
+            params, {"tokens": jnp.asarray(prompts[i][None, :])})
+        key = jax.random.key(eng.cfg.seed + i)
+        out = []
+        for pos in range(len(prompts[i]), len(prompts[i]) + 6):
+            row = logits[0, -1]
+            if t > 0:
+                key, sub = jax.random.split(key)
+                out.append(int(jax.random.categorical(sub, row / t)))
+            else:
+                out.append(int(jnp.argmax(row)))
+            logits, caches = session._decode(
+                params, jnp.asarray([[out[-1]]], jnp.int32),
+                jnp.int32(pos), caches)
+        np.testing.assert_array_equal(done[i], out)
 
 
 # ---------------------------------------------------------------------------

@@ -104,6 +104,32 @@ def test_batched_stream_matches_solo_sessions():
         np.testing.assert_array_equal(done[i].result, req.result)
 
 
+def test_sampled_stream_matches_solo_sessions():
+    """A greedy and a sampled request sharing a session each emit the
+    tokens of being served alone: the sampled slot consumes one split of
+    its own key per token from the session's device-resident key array,
+    and the greedy slot none."""
+    model, params = reduced_model("olmo-1b")
+    prompts = _prompts(model.cfg, [6, 8], seed=7)
+    temps = [0.0, 0.9]
+
+    def serve(uids, max_batch, temp=None):
+        eng = _session(model, params, max_batch=max_batch)
+        for u in uids:
+            eng.submit(GenRequest(uid=u, tokens=prompts[u], max_new_tokens=7,
+                                  temperature=temps[u] if temp is None
+                                  else temp))
+        return {r.uid: r.result for r in eng.run()}
+
+    batched = serve([0, 1], max_batch=2)
+    for u in (0, 1):
+        np.testing.assert_array_equal(batched[u],
+                                      serve([u], max_batch=1)[u])
+    # The sampled request did sample: served greedily it reads otherwise.
+    assert not np.array_equal(batched[1],
+                              serve([1], max_batch=1, temp=0.0)[1])
+
+
 def test_join_lands_in_next_group_and_evicted_never_reencoded():
     model, params = reduced_model("olmo-1b")
     eng = _session(model, params, max_batch=2)
@@ -138,11 +164,21 @@ def test_evicted_slot_cache_rows_are_freed():
         eng.step()
     slot1 = next(r for r in eng.completed if r.uid == 1).slot
     slot0 = 1 - slot1
-    for tree in (eng._head_caches, eng._tail_caches):
-        for leaf in jax.tree.leaves(tree):
-            assert not np.any(np.asarray(leaf[slot1]))      # freed
-    assert any(np.any(np.asarray(leaf[slot0]))
-               for leaf in jax.tree.leaves(eng._tail_caches))
+
+    def assert_freed():
+        for tree in (eng._head_caches, eng._tail_caches):
+            for leaf in jax.tree.leaves(tree):
+                assert not np.any(np.asarray(leaf[slot1]))      # freed
+        assert any(np.any(np.asarray(leaf[slot0]))
+                   for leaf in jax.tree.leaves(eng._tail_caches))
+
+    assert_freed()
+    # The donated step programs advance only the live slot: the freed
+    # rows stay zero while uid 0 goes on decoding.
+    for _ in range(3):
+        eng.step()
+        assert eng._active_slots() == [slot0]
+        assert_freed()
 
 
 def test_cross_session_group_matches_separate_sessions():
