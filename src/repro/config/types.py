@@ -49,15 +49,40 @@ class ModelConfig:
     # ``window_only_for_long`` (keeps other shapes paper-exact full attn).
     window_only_for_long: bool = True
 
+    # Multi-head latent attention (DeepSeek-V2/V3, arXiv:2405.04434): on
+    # when ``kv_lora_rank`` > 0. Keys and values come from one latent of
+    # ``kv_lora_rank`` per position plus a rope key of ``qk_rope_head_dim``
+    # shared by all heads; those two are what the cache holds.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # Norm flavour.
     norm_kind: str = "rmsnorm"       # "rmsnorm" | "layernorm" | "nonparametric"
     tie_embeddings: bool = False
+    # Token embeddings multiplied by sqrt(d_model) on entry (OLMo as this
+    # repository runs it); DeepSeek-V3 models take them as they are.
+    scale_embeddings: bool = True
 
     # MoE.
     num_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0                # 0 -> d_ff
     router_aux_loss: float = 0.01
+    # Router scores: "softmax" over all experts, or "sigmoid" with a
+    # learned correction bias that only picks the top-k (DeepSeek-V3's
+    # noaux_tc); the chosen scores are normalised over the k, then scaled.
+    scoring_func: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # Shared experts: one SwiGLU of width n_shared_experts * moe_d_ff that
+    # every token passes through.
+    n_shared_experts: int = 0
+    # The expert share this model holds (expert parallelism): experts
+    # [expert_lo, expert_lo + experts_held) of num_experts. The router
+    # keeps all num_experts outputs; 0 held means all of them.
+    expert_lo: int = 0
+    experts_held: int = 0
 
     # SSM / hybrid.
     ssm_state_dim: int = 0
@@ -110,6 +135,14 @@ class ModelConfig:
     def moe_d_ff_(self) -> int:
         return self.moe_d_ff or self.d_ff
 
+    @property
+    def experts_held_(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -125,6 +158,9 @@ class ModelConfig:
         while heads % kv:
             kv -= 1
         pattern = self.block_pattern[:2] if self.block_pattern else ""
+        experts = min(self.num_experts, 4)
+        # A share stays a share: at most half the reduced experts held.
+        held = min(self.experts_held, experts // 2) if self.experts_held else 0
         return self.replace(
             num_layers=min(self.num_layers, 2) if self.num_layers else 0,
             d_model=d_model,
@@ -133,8 +169,15 @@ class ModelConfig:
             head_dim=64,
             d_ff=min(self.d_ff, 512) if self.d_ff else 0,
             vocab_size=min(self.vocab_size, 512) if self.vocab_size else 0,
-            num_experts=min(self.num_experts, 4) if self.num_experts else 0,
+            num_experts=experts,
+            experts_per_token=min(self.experts_per_token, experts // 2),
             moe_d_ff=min(self.moe_d_ff_, 512) if self.num_experts else 0,
+            expert_lo=min(self.expert_lo, experts - held) if held else 0,
+            experts_held=held,
+            kv_lora_rank=min(self.kv_lora_rank, 64),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+            v_head_dim=min(self.v_head_dim, 32),
             ssm_state_dim=min(self.ssm_state_dim, 16) if self.ssm_state_dim else 0,
             block_pattern=pattern,
             shared_attention_every=(2 if self.shared_attention_every else 0),

@@ -77,10 +77,10 @@ class Model:
             moe_layers = tf_lib.default_pattern(cfg).count("e")
             inactive = (
                 moe_layers
-                * (cfg.num_experts - cfg.experts_per_token)
+                * (cfg.experts_held_ - _routed_held(cfg))
                 * per_expert
             )
-            return total - inactive
+            return round(total - inactive)
         return total
 
     # ------------------------------------------------------------ entries
@@ -309,31 +309,31 @@ class Model:
                              "CNNs decouple per request (run_head/run_tail)")
         tf_lib.check_streamable(self.cfg)
 
-    def prefill_head(self, params, batch, cache_len: int, point: int
-                     ) -> Tuple[jnp.ndarray, List[Any]]:
-        """Edge prefill of blocks [0, point]; returns (boundary, caches)."""
+    def prefill_head(self, params, batch, cache_len: int, point: int):
+        """Edge prefill of blocks [0, point]; returns (boundary, caches,
+        routes): its expert layers' chosen expert ids (layers, B, S, k),
+        None where it holds none."""
         self._check_token_split()
         return tf_lib.prefill_head(params, self.cfg, batch, cache_len, point)
 
-    def prefill_tail(self, params, boundary, cache_len: int, point: int
-                     ) -> Tuple[jnp.ndarray, List[Any]]:
+    def prefill_tail(self, params, boundary, cache_len: int, point: int):
         """Cloud prefill resuming at block point+1 from the decoded
-        boundary; returns (logits, caches)."""
+        boundary; returns (logits, caches, routes)."""
         self._check_token_split()
         return tf_lib.prefill_tail(params, self.cfg, boundary, cache_len,
                                    point)
 
     def decode_head(self, params, tokens, pos, head_caches, point: int,
-                    seq_hint: int) -> Tuple[jnp.ndarray, List[Any]]:
+                    seq_hint: int):
         """Edge half of one decode step; returns (boundary (B,1,d),
-        new head caches)."""
+        new head caches, routes (layers, B, 1, k) or None)."""
         return tf_lib.decode_head(params, self.cfg, tokens, pos, head_caches,
                                   point, seq_hint)
 
     def decode_tail(self, params, boundary, pos, tail_caches, point: int,
-                    seq_hint: int) -> Tuple[jnp.ndarray, List[Any]]:
+                    seq_hint: int):
         """Cloud half of one decode step; returns (logits (B,1,V),
-        new tail caches)."""
+        new tail caches, routes (layers, B, 1, k) or None)."""
         return tf_lib.decode_tail(params, self.cfg, boundary, pos,
                                   tail_caches, point, seq_hint)
 
@@ -406,7 +406,7 @@ class Model:
             if cfg.shared_attention_every:
                 n_attn += len(tf_lib.default_pattern(cfg)) \
                     // cfg.shared_attention_every
-            fwd += 4.0 * b * cfg.num_heads * s * kv_len * cfg.head_dim_ \
+            fwd += 4.0 * b * cfg.num_heads * s * kv_len * _qkv_width(cfg) \
                 * n_attn
             if cfg.is_encdec:
                 enc_s = self.enc_len_for(s)
@@ -437,7 +437,8 @@ class Model:
         if cfg.shared_attention_every:
             n_attn += len(tf_lib.default_pattern(cfg)) \
                 // cfg.shared_attention_every
-        fwd += 4.0 * b * cfg.num_heads * cache_len * cfg.head_dim_ * n_attn
+        fwd += 4.0 * b * cfg.num_heads * cache_len * _qkv_width(cfg) \
+            * n_attn
         if cfg.is_encdec:
             fwd += 4.0 * b * cfg.num_heads * self.enc_len_for(s) \
                 * cfg.head_dim_ * len(tf_lib.default_pattern(cfg))
@@ -475,16 +476,16 @@ def _transformer_head(model: Model, params, batch, point: int):
         seg = plan[sj]
         count = seg.count if sj < si else off + 1
         if seg.shared:
-            x, _, _ = tf_lib.blk.block_apply_seq(
+            x = tf_lib.blk.block_apply_seq(
                 "A", params["shared_attn"], x, ctx, cfg
-            )
+            )[0]
             continue
         seg_params = _slice_seg(params["segments"][sj], 0, count)
 
         def body(carry, layer_params, kind=seg.kind):
             h, = carry
-            h, _, _ = tf_lib.blk.block_apply_seq(kind, layer_params, h, ctx,
-                                                 cfg)
+            h = tf_lib.blk.block_apply_seq(kind, layer_params, h, ctx,
+                                           cfg)[0]
             return (h,), None
 
         (x,), _ = jax.lax.scan(body, (x,), seg_params)
@@ -509,16 +510,16 @@ def _transformer_tail(model: Model, params, boundary, point: int, extras):
         if seg.shared:
             if sj == si:   # the cut block itself was already run in the head
                 continue
-            x, _, _ = tf_lib.blk.block_apply_seq(
+            x = tf_lib.blk.block_apply_seq(
                 "A", params["shared_attn"], x, ctx, cfg
-            )
+            )[0]
             continue
         seg_params = _slice_seg(params["segments"][sj], lo, seg.count)
 
         def body(carry, layer_params, kind=seg.kind):
             h, = carry
-            h, _, _ = tf_lib.blk.block_apply_seq(kind, layer_params, h, ctx,
-                                                 cfg)
+            h = tf_lib.blk.block_apply_seq(kind, layer_params, h, ctx,
+                                           cfg)[0]
             return (h,), None
 
         (x,), _ = jax.lax.scan(body, (x,), seg_params)
@@ -547,30 +548,55 @@ def _transformer_segment(model: Model, params, boundary, from_point: int,
         if seg.shared:
             if sj == si:   # the cut block itself was already run upstream
                 continue
-            x, _, _ = tf_lib.blk.block_apply_seq(
+            x = tf_lib.blk.block_apply_seq(
                 "A", params["shared_attn"], x, ctx, cfg
-            )
+            )[0]
             continue
         seg_params = _slice_seg(params["segments"][sj], lo, hi)
 
         def body(carry, layer_params, kind=seg.kind):
             h, = carry
-            h, _, _ = tf_lib.blk.block_apply_seq(kind, layer_params, h, ctx,
-                                                 cfg)
+            h = tf_lib.blk.block_apply_seq(kind, layer_params, h, ctx,
+                                           cfg)[0]
             return (h,), None
 
         (x,), _ = jax.lax.scan(body, (x,), seg_params)
     return x, extras
 
 
+def _routed_held(cfg: ModelConfig) -> float:
+    """Expected experts of the held share a token is routed to: its k
+    choices, each held with probability experts_held / num_experts."""
+    return cfg.experts_per_token * cfg.experts_held_ / cfg.num_experts
+
+
+def _qkv_width(cfg: ModelConfig) -> float:
+    """Mean of a head's query-key and value widths: the FMACs of one
+    head's scores and weighted values at one position, over 2. Latent
+    attention counts them in the published (non-absorbed) form."""
+    if cfg.is_mla:
+        return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                + cfg.v_head_dim) / 2
+    return cfg.head_dim_
+
+
 def _block_fmacs_per_token(cfg: ModelConfig) -> List[float]:
-    """Per-token FMACs of each block (weights touched once per token)."""
+    """Per-token FMACs of each block (weights touched once per token). An
+    expert block counts its router over all experts, the routed experts of
+    the held share (expected) and the shared experts."""
     d, hd = cfg.d_model, cfg.head_dim_
     h, kv = cfg.num_heads, cfg.num_kv_heads
     out: List[float] = []
     attn = d * (h + 2 * kv) * hd + h * hd * d       # qkv + out proj
+    if cfg.is_mla:
+        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        r, vd = cfg.kv_lora_rank, cfg.v_head_dim
+        attn = (d * h * (nope + rope) + d * (r + rope)
+                + r * h * (nope + vd) + h * vd * d)
     dense_mlp = 3.0 * d * cfg.d_ff
-    moe_mlp = 3.0 * d * cfg.moe_d_ff_ * cfg.experts_per_token
+    f = cfg.moe_d_ff_
+    moe_mlp = (3.0 * d * f * (_routed_held(cfg) + cfg.n_shared_experts)
+               if cfg.num_experts else 0.0)
     for kind in tf_lib.default_pattern(cfg):
         if kind == "d":
             out.append(attn + dense_mlp)

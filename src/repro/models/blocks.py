@@ -14,8 +14,11 @@ run of identical blocks whose stacked parameters are consumed by one
   'c'  decoder-with-cross-attention block              — seamless
 
 Each kind provides: ``spec`` (ParamSpec tree), ``apply_seq`` (full sequence;
-returns (x, aux, cache_entry)) and ``apply_decode`` (one token; returns
-(x, new_cache_entry)).
+returns (x, aux, cache_entry, routes)) and ``apply_decode`` (one token;
+returns (x, new_cache_entry, routes)); ``routes`` are an 'e' block's chosen
+expert ids (B, S, k) int32, None for the other kinds. The 'd' and 'e' blocks take multi-head latent
+attention (``layers/mla.py``) where the config sets ``kv_lora_rank``; their
+cache entry is then the latent (``c_kv`` and ``k_pe`` a position).
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ import jax.numpy as jnp
 from repro.config.types import ModelConfig
 from repro.models.layers import attention as attn_lib
 from repro.models.layers import mamba2 as mamba_lib
+from repro.models.layers import mla as mla_lib
 from repro.models.layers import xlstm as xlstm_lib
 from repro.models.layers.mlp import (
     apply_gelu_mlp,
@@ -34,7 +38,7 @@ from repro.models.layers.mlp import (
     gelu_mlp_spec,
     swiglu_spec,
 )
-from repro.models.layers.moe import apply_moe, moe_spec
+from repro.models.layers.moe import apply_moe, apply_moe_decode, moe_spec
 from repro.models.layers.norms import apply_norm, norm_spec
 
 
@@ -59,12 +63,18 @@ class DecodeContext(NamedTuple):
 # ---------------------------------------------------------------------------
 
 
+def _mla(kind: str, cfg: ModelConfig) -> bool:
+    """Whether a block of this kind attends with latent attention."""
+    return kind in ("d", "e") and cfg.is_mla
+
+
 def block_spec(kind: str, cfg: ModelConfig):
     d, dt_ = cfg.d_model, cfg.param_dtype
     if kind in ("d", "e", "A"):
         p = {
             "ln1": norm_spec(cfg.norm_kind, d, dt_),
-            "attn": attn_lib.attention_spec(cfg),
+            "attn": (mla_lib.mla_spec(cfg) if _mla(kind, cfg)
+                     else attn_lib.attention_spec(cfg)),
             "ln2": norm_spec(cfg.norm_kind, d, dt_),
         }
         p["mlp"] = moe_spec(cfg) if kind == "e" else swiglu_spec(d, cfg.d_ff, dt_)
@@ -128,6 +138,8 @@ def init_block_cache(kind: str, cfg: ModelConfig, batch: int, cache_len: int,
                      dtype, enc_len: int = 0):
     """Zero cache entry for ONE block of this kind (unstacked)."""
     kv, hd = cfg.num_kv_heads, cfg.head_dim_
+    if _mla(kind, cfg):
+        return mla_lib.init_cache(cfg, batch, cache_len, dtype)
     if kind in ("d", "e", "A"):
         return _kv_cache_entry(cfg, batch, cache_len, dtype)
     if kind == "m":
@@ -153,6 +165,8 @@ def block_cache_axes(kind: str, cfg: ModelConfig = None):
     kv4 = ("batch", "kv_seq", "kv_heads", "head_dim")
     kv3 = ("batch", "kv_seq", "kv_heads")
     q8 = cfg is not None and cfg.kv_cache_bits == 8
+    if cfg is not None and _mla(kind, cfg):
+        return mla_lib.cache_axes(cfg)
     if kind in ("d", "e", "A"):
         if q8:
             return {"k": kv4, "ks": kv3, "v": kv4, "vs": kv3}
@@ -191,10 +205,28 @@ def block_cache_axes(kind: str, cfg: ModelConfig = None):
 
 def block_apply_seq(
     kind: str, params, x: jnp.ndarray, ctx: SeqContext, cfg: ModelConfig
-) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
-    """Returns (x_new, aux_loss, cache_entry_or_None)."""
+) -> Tuple[jnp.ndarray, jnp.ndarray, Any, Any]:
+    """Returns (x_new, aux_loss, cache_entry_or_None, routes_or_None)."""
     aux = jnp.zeros((), jnp.float32)
+    routes = None
     s = x.shape[1]
+
+    if _mla(kind, cfg):
+        h = apply_norm(cfg.norm_kind, params["ln1"], x)
+        q_nope, q_pe, c_kv, k_pe = mla_lib.project(params["attn"], h,
+                                                   ctx.positions, cfg)
+        x = x + mla_lib.attend_seq(params["attn"], q_nope, q_pe, c_kv, k_pe,
+                                   cfg, ctx.window)
+        h2 = apply_norm(cfg.norm_kind, params["ln2"], x)
+        if kind == "e":
+            y, aux, routes = apply_moe(params["mlp"], h2, cfg)
+        else:
+            y = apply_swiglu(params["mlp"], h2)
+        cache = None
+        if ctx.cache_len:
+            cache = mla_lib.build_cache(c_kv, k_pe, ctx.cache_len,
+                                        _ring_place, cfg)
+        return x + y, aux, cache, routes
 
     if kind in ("d", "e", "A", "E"):
         h = apply_norm(cfg.norm_kind if kind != "E" else "layernorm",
@@ -212,7 +244,7 @@ def block_apply_seq(
         h2 = apply_norm(cfg.norm_kind if kind != "E" else "layernorm",
                         params["ln2"], x)
         if kind == "e":
-            y, aux = apply_moe(params["mlp"], h2, cfg)
+            y, aux, routes = apply_moe(params["mlp"], h2, cfg)
         elif kind == "E":
             y = apply_gelu_mlp(params["mlp"], h2)
         else:
@@ -221,26 +253,28 @@ def block_apply_seq(
         cache = None
         if ctx.cache_len and kind != "E":
             cache = _build_kv_cache(k, v, s, ctx.cache_len, cfg)
-        return x, aux, cache
+        return x, aux, cache, routes
 
     if kind == "m":
         h = apply_norm(cfg.norm_kind, params["ln"], x)
         # For prefill we need the final SSM/conv state: use the stateful path.
         if ctx.cache_len:
             y, state = _mamba_seq_with_state(params["mamba"], h, cfg)
-            return x + y, aux, state._asdict()
+            return x + y, aux, state._asdict(), None
         y = mamba_lib.apply_mamba2(params["mamba"], h, cfg)
-        return x + y, aux, None
+        return x + y, aux, None, None
 
     if kind == "l":
         h = apply_norm(cfg.norm_kind, params["ln"], x)
         y, state = xlstm_lib.apply_mlstm(params["mlstm"], h, cfg)
-        return x + y, aux, state._asdict() if ctx.cache_len else None
+        return (x + y, aux, state._asdict() if ctx.cache_len else None,
+                None)
 
     if kind == "s":
         h = apply_norm(cfg.norm_kind, params["ln"], x)
         y, state = xlstm_lib.apply_slstm(params["slstm"], h, cfg)
-        return x + y, aux, state._asdict() if ctx.cache_len else None
+        return (x + y, aux, state._asdict() if ctx.cache_len else None,
+                None)
 
     if kind == "c":
         h = apply_norm("layernorm", params["ln1"], x)
@@ -256,7 +290,7 @@ def block_apply_seq(
         if ctx.cache_len:
             cache = _build_kv_cache(k, v, s, ctx.cache_len, cfg)
             cache.update({"xk": xk, "xv": xv})
-        return x, aux, cache
+        return x, aux, cache, routes
 
     raise ValueError(kind)
 
@@ -320,8 +354,20 @@ def _mamba_seq_with_state(params, h, cfg):
 def block_apply_decode(
     kind: str, params, x: jnp.ndarray, cache, ctx: DecodeContext,
     cfg: ModelConfig
-) -> Tuple[jnp.ndarray, Any]:
-    """x: (B, 1, d). Returns (x_new, cache_new)."""
+) -> Tuple[jnp.ndarray, Any, Any]:
+    """x: (B, 1, d). Returns (x_new, cache_new, routes): an 'e' block's
+    chosen expert ids (B, 1, k) int32, None for the other kinds."""
+    if _mla(kind, cfg):
+        h = apply_norm(cfg.norm_kind, params["ln1"], x)
+        out, new_cache = mla_lib.decode(params["attn"], h, cache, ctx.pos,
+                                        cfg)
+        x = x + out
+        h2 = apply_norm(cfg.norm_kind, params["ln2"], x)
+        if kind == "e":
+            y, routes = apply_moe_decode(params["mlp"], h2, cfg)
+            return x + y, new_cache, routes
+        return x + apply_swiglu(params["mlp"], h2), new_cache, None
+
     if kind in ("d", "e", "A", "c"):
         norm_kind = cfg.norm_kind if kind != "c" else "layernorm"
         h = apply_norm(norm_kind, params["ln1"], x)
@@ -352,30 +398,31 @@ def block_apply_decode(
                 params["xattn"], hx, cache["xk"], cache["xv"]
             )
         norm2 = apply_norm(norm_kind, params["ln2"], x)
+        routes = None
         if kind == "e":
-            y, _ = apply_moe(params["mlp"], norm2, cfg)
+            y, routes = apply_moe_decode(params["mlp"], norm2, cfg)
         elif kind == "c":
             y = apply_gelu_mlp(params["mlp"], norm2)
         else:
             y = apply_swiglu(params["mlp"], norm2)
-        return x + y, new_cache
+        return x + y, new_cache, routes
 
     if kind == "m":
         h = apply_norm(cfg.norm_kind, params["ln"], x)
         state = mamba_lib.MambaState(**cache)
         y, state = mamba_lib.decode_mamba2(params["mamba"], h, state, cfg)
-        return x + y, state._asdict()
+        return x + y, state._asdict(), None
 
     if kind == "l":
         h = apply_norm(cfg.norm_kind, params["ln"], x)
         state = xlstm_lib.MLSTMState(**cache)
         y, state = xlstm_lib.apply_mlstm(params["mlstm"], h, cfg, state)
-        return x + y, state._asdict()
+        return x + y, state._asdict(), None
 
     if kind == "s":
         h = apply_norm(cfg.norm_kind, params["ln"], x)
         state = xlstm_lib.SLSTMState(**cache)
         y, state = xlstm_lib.apply_slstm(params["slstm"], h, cfg, state)
-        return x + y, state._asdict()
+        return x + y, state._asdict(), None
 
     raise ValueError(kind)

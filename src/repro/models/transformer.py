@@ -149,13 +149,19 @@ def _vision_positions_3d(n_vis: int, text_len: int, batch: int) -> jnp.ndarray:
     )
 
 
+def embed_tokens(params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
+    """Token embeddings, times sqrt(d_model) where the config scales them."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.scale_embeddings:
+        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    return x
+
+
 def embed_inputs(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray]):
     """Token (+ modality-stub) embedding. Returns (x, positions, pos3d)."""
     tokens = batch["tokens"]
     b = tokens.shape[0]
-    x = jnp.take(params["embed"], tokens, axis=0)
-    scale = jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-    x = x * scale
+    x = embed_tokens(params, cfg, tokens)
     if cfg.family == "vlm" and "vision_embeds" in batch:
         vis = jnp.einsum("bnd,de->bne", batch["vision_embeds"].astype(x.dtype),
                          params["vision_proj"])
@@ -181,7 +187,7 @@ def run_encoder(params, cfg: ModelConfig, src: jnp.ndarray) -> jnp.ndarray:
 
     def body(carry, layer_params):
         h, = carry
-        h, _, _ = blk.block_apply_seq("E", layer_params, h, ctx, cfg)
+        h = blk.block_apply_seq("E", layer_params, h, ctx, cfg)[0]
         return (constrain(h, _HID),), None
 
     if cfg.block_remat:
@@ -221,7 +227,7 @@ def forward_seq(
     caches: List[Any] = []
     for seg, seg_params in zip(plan, params["segments"]):
         if seg.shared:
-            x, aux, cache = blk.block_apply_seq(
+            x, aux, cache, _ = blk.block_apply_seq(
                 "A", params["shared_attn"], x, ctx, cfg
             )
             aux_total += aux
@@ -230,7 +236,8 @@ def forward_seq(
 
         def body(carry, layer_params, kind=seg.kind):
             h, aux_acc = carry
-            h, aux, cache = blk.block_apply_seq(kind, layer_params, h, ctx, cfg)
+            h, aux, cache, _ = blk.block_apply_seq(kind, layer_params, h, ctx,
+                                                   cfg)
             return (constrain(h, _HID), aux_acc + aux), cache
 
         if cfg.block_remat:
@@ -298,19 +305,48 @@ def decode_step(
 ) -> Tuple[jnp.ndarray, List[Any]]:
     """One decode step. Returns (logits (B,1,V), new caches)."""
     plan = segment_plan(cfg)
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = constrain(x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype), _HID)
+    x = constrain(embed_tokens(params, cfg, tokens), _HID)
     window = effective_window(cfg, int(_decode_seq_hint(cfg, caches)))
+    ctx = _decode_context(cfg, x, pos, window)
+    runs = [(sj, 0, seg.count) for sj, seg in enumerate(plan)]
+    x, new_caches, _ = decode_blocks(params, cfg, x, ctx, runs, caches)
+    return _logits(params, cfg, x), new_caches
+
+
+def _decode_context(cfg: ModelConfig, x, pos, window: int):
     pos3d = None
     if cfg.rope_kind == "mrope":
         p = jnp.broadcast_to(pos, (x.shape[0], 1))
         pos3d = jnp.stack([p, p, p], axis=-1)
-    ctx = blk.DecodeContext(pos, window, pos3d)
+    return blk.DecodeContext(pos, window, pos3d)
 
+
+def _runs_params(params, seg, sj: int, lo: int, hi: int):
+    """Layers ``[lo, hi)`` of segment ``sj``'s stacked weights."""
+    seg_params = params["segments"][sj]
+    if (lo, hi) != (0, seg.count):
+        seg_params = _slice_layers(seg_params, lo, hi)
+    return seg_params
+
+
+def _stack_routes(routes: List[Any]):
+    """The expert layers' routes, in layer order, as one (expert layers,
+    B, S, k) int32 array; None where the runs hold no expert layer."""
+    return jnp.concatenate(routes) if routes else None
+
+
+def decode_blocks(params, cfg: ModelConfig, x, ctx, runs, caches):
+    """One token through layers ``[lo, hi)`` of each segment ``sj`` in
+    ``runs`` (a shared block runs whole), each with its cache. Returns (x,
+    new caches, routes): each expert layer's chosen expert ids (expert
+    layers, B, 1, k) int32, None where the runs hold no expert layer."""
+    plan = segment_plan(cfg)
     new_caches: List[Any] = []
-    for seg, seg_params, cache in zip(plan, params["segments"], caches):
+    routes: List[Any] = []
+    for (sj, lo, hi), cache in zip(runs, caches):
+        seg = plan[sj]
         if seg.shared:
-            x, new_c = blk.block_apply_decode(
+            x, new_c, _ = blk.block_apply_decode(
                 "A", params["shared_attn"], x, cache, ctx, cfg
             )
             new_caches.append(new_c)
@@ -318,18 +354,51 @@ def decode_step(
 
         def body(h, xs, kind=seg.kind):
             layer_params, layer_cache = xs
-            h, new_c = blk.block_apply_decode(kind, layer_params, h,
-                                              layer_cache, ctx, cfg)
-            return constrain(h, _HID), new_c
+            h, new_c, r = blk.block_apply_decode(kind, layer_params, h,
+                                                 layer_cache, ctx, cfg)
+            return constrain(h, _HID), (new_c, r)
 
-        x, cache_stack = jax.lax.scan(
-            body, x, (seg_params, cache),
-            unroll=seg.count if cfg.scan_unroll else 1,
+        x, (cache_stack, r) = jax.lax.scan(
+            body, x, (_runs_params(params, seg, sj, lo, hi), cache),
+            unroll=(hi - lo) if cfg.scan_unroll else 1,
         )
         new_caches.append(cache_stack)
+        if r is not None:
+            routes.append(r)
+    return x, new_caches, _stack_routes(routes)
 
-    logits = _logits(params, cfg, x)
-    return logits, new_caches
+
+def seq_blocks(params, cfg: ModelConfig, x, ctx, runs):
+    """A sequence through layers ``[lo, hi)`` of each segment ``sj`` in
+    ``runs`` (a shared block runs whole), building their caches. Returns
+    (x, caches, routes): each expert layer's chosen expert ids (expert
+    layers, B, S, k) int32, None where the runs hold no expert layer."""
+    plan = segment_plan(cfg)
+    caches: List[Any] = []
+    routes: List[Any] = []
+    for sj, lo, hi in runs:
+        seg = plan[sj]
+        if seg.shared:
+            x, _, cache, _ = blk.block_apply_seq(
+                "A", params["shared_attn"], x, ctx, cfg
+            )
+            caches.append(cache)
+            continue
+
+        def body(carry, layer_params, kind=seg.kind):
+            h, = carry
+            h, _, cache, r = blk.block_apply_seq(kind, layer_params, h, ctx,
+                                                 cfg)
+            return (constrain(h, _HID),), (cache, r)
+
+        (x,), (cache_stack, r) = jax.lax.scan(
+            body, (x,), _runs_params(params, seg, sj, lo, hi),
+            unroll=(hi - lo) if cfg.scan_unroll else 1,
+        )
+        caches.append(cache_stack)
+        if r is not None:
+            routes.append(r)
+    return x, caches, _stack_routes(routes)
 
 
 def _decode_seq_hint(cfg: ModelConfig, caches) -> int:
@@ -443,48 +512,25 @@ def _slice_layers(seg_params, lo: int, hi: int):
 
 
 def prefill_head(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],
-                 cache_len: int, point: int
-                 ) -> Tuple[jnp.ndarray, List[Any]]:
+                 cache_len: int, point: int):
     """Edge prefill: run blocks [0, point] over the prompt, building only
-    the head's decode caches. Returns (boundary (B, S, d), head_caches)."""
+    the head's decode caches. Returns (boundary (B, S, d), head_caches,
+    routes (``seq_blocks``))."""
     check_streamable(cfg)
-    plan = segment_plan(cfg)
     x, positions, pos3d = embed_inputs(params, cfg, batch)
     x = constrain(x, _HID)
     window = effective_window(cfg, x.shape[1])
     ctx = blk.SeqContext(positions, pos3d, window, cache_len, None)
-
-    caches: List[Any] = []
-    for sj, count in _head_segments(cfg, point):
-        seg = plan[sj]
-        if seg.shared:
-            x, _, cache = blk.block_apply_seq(
-                "A", params["shared_attn"], x, ctx, cfg
-            )
-            caches.append(cache)
-            continue
-        seg_params = _slice_layers(params["segments"][sj], 0, count)
-
-        def body(carry, layer_params, kind=seg.kind):
-            h, = carry
-            h, _, cache = blk.block_apply_seq(kind, layer_params, h, ctx, cfg)
-            return (constrain(h, _HID),), cache
-
-        (x,), cache_stack = jax.lax.scan(
-            body, (x,), seg_params,
-            unroll=count if cfg.scan_unroll else 1,
-        )
-        caches.append(cache_stack)
-    return x, caches
+    runs = [(sj, 0, count) for sj, count in _head_segments(cfg, point)]
+    return seq_blocks(params, cfg, x, ctx, runs)
 
 
 def prefill_tail(params, cfg: ModelConfig, boundary: jnp.ndarray,
-                 cache_len: int, point: int
-                 ) -> Tuple[jnp.ndarray, List[Any]]:
+                 cache_len: int, point: int):
     """Cloud prefill: resume at block point+1 from the decoded boundary,
     building the tail's decode caches. Positions are rebuilt from the
     boundary shape (decoder-only streams: plain arange). Returns
-    (logits (B, S, V), tail_caches)."""
+    (logits (B, S, V), tail_caches, routes (``seq_blocks``))."""
     check_streamable(cfg)
     plan = segment_plan(cfg)
     b, s = boundary.shape[0], boundary.shape[1]
@@ -492,114 +538,38 @@ def prefill_tail(params, cfg: ModelConfig, boundary: jnp.ndarray,
     window = effective_window(cfg, s)
     ctx = blk.SeqContext(positions, None, window, cache_len, None)
     x = constrain(boundary, _HID)
-
-    caches: List[Any] = []
-    for sj, lo in _tail_segments(cfg, point):
-        seg = plan[sj]
-        if seg.shared:
-            x, _, cache = blk.block_apply_seq(
-                "A", params["shared_attn"], x, ctx, cfg
-            )
-            caches.append(cache)
-            continue
-        seg_params = _slice_layers(params["segments"][sj], lo, seg.count)
-
-        def body(carry, layer_params, kind=seg.kind):
-            h, = carry
-            h, _, cache = blk.block_apply_seq(kind, layer_params, h, ctx, cfg)
-            return (constrain(h, _HID),), cache
-
-        (x,), cache_stack = jax.lax.scan(
-            body, (x,), seg_params,
-            unroll=(seg.count - lo) if cfg.scan_unroll else 1,
-        )
-        caches.append(cache_stack)
-    logits = _logits(params, cfg, x)
-    return logits, caches
+    runs = [(sj, lo, plan[sj].count) for sj, lo in _tail_segments(cfg, point)]
+    x, caches, routes = seq_blocks(params, cfg, x, ctx, runs)
+    return _logits(params, cfg, x), caches, routes
 
 
 def decode_head(params, cfg: ModelConfig, tokens: jnp.ndarray,
                 pos: jnp.ndarray, head_caches: List[Any], point: int,
-                seq_hint: int) -> Tuple[jnp.ndarray, List[Any]]:
+                seq_hint: int):
     """Edge half of one decode step: blocks [0, point] on one new token.
     ``seq_hint`` is the nominal sequence length (the shared cache length),
     passed explicitly because the head's caches may not include an
     attention cache to recover it from. Returns (boundary (B, 1, d),
-    new head caches)."""
-    plan = segment_plan(cfg)
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = constrain(x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype), _HID)
-    window = effective_window(cfg, seq_hint)
-    pos3d = None
-    if cfg.rope_kind == "mrope":
-        p = jnp.broadcast_to(pos, (x.shape[0], 1))
-        pos3d = jnp.stack([p, p, p], axis=-1)
-    ctx = blk.DecodeContext(pos, window, pos3d)
-
-    new_caches: List[Any] = []
-    for (sj, count), cache in zip(_head_segments(cfg, point), head_caches):
-        seg = plan[sj]
-        if seg.shared:
-            x, new_c = blk.block_apply_decode(
-                "A", params["shared_attn"], x, cache, ctx, cfg
-            )
-            new_caches.append(new_c)
-            continue
-        seg_params = _slice_layers(params["segments"][sj], 0, count)
-
-        def body(h, xs, kind=seg.kind):
-            layer_params, layer_cache = xs
-            h, new_c = blk.block_apply_decode(kind, layer_params, h,
-                                              layer_cache, ctx, cfg)
-            return constrain(h, _HID), new_c
-
-        x, cache_stack = jax.lax.scan(
-            body, x, (seg_params, cache),
-            unroll=count if cfg.scan_unroll else 1,
-        )
-        new_caches.append(cache_stack)
-    return x, new_caches
+    new head caches, routes (``decode_blocks``))."""
+    x = constrain(embed_tokens(params, cfg, tokens), _HID)
+    ctx = _decode_context(cfg, x, pos, effective_window(cfg, seq_hint))
+    runs = [(sj, 0, count) for sj, count in _head_segments(cfg, point)]
+    return decode_blocks(params, cfg, x, ctx, runs, head_caches)
 
 
 def decode_tail(params, cfg: ModelConfig, boundary: jnp.ndarray,
                 pos: jnp.ndarray, tail_caches: List[Any], point: int,
-                seq_hint: int) -> Tuple[jnp.ndarray, List[Any]]:
+                seq_hint: int):
     """Cloud half of one decode step: resume at block point+1 from the
     decoded (B, 1, d) boundary row. Returns (logits (B, 1, V), new tail
-    caches)."""
+    caches, routes (``decode_blocks``))."""
     plan = segment_plan(cfg)
     x = constrain(boundary, _HID)
-    window = effective_window(cfg, seq_hint)
-    pos3d = None
-    if cfg.rope_kind == "mrope":
-        p = jnp.broadcast_to(pos, (x.shape[0], 1))
-        pos3d = jnp.stack([p, p, p], axis=-1)
-    ctx = blk.DecodeContext(pos, window, pos3d)
-
-    new_caches: List[Any] = []
-    for (sj, lo), cache in zip(_tail_segments(cfg, point), tail_caches):
-        seg = plan[sj]
-        if seg.shared:
-            x, new_c = blk.block_apply_decode(
-                "A", params["shared_attn"], x, cache, ctx, cfg
-            )
-            new_caches.append(new_c)
-            continue
-        seg_params = _slice_layers(params["segments"][sj], lo, seg.count)
-
-        def body(h, xs, kind=seg.kind):
-            layer_params, layer_cache = xs
-            h, new_c = blk.block_apply_decode(kind, layer_params, h,
-                                              layer_cache, ctx, cfg)
-            return constrain(h, _HID), new_c
-
-        x, cache_stack = jax.lax.scan(
-            body, x, (seg_params, cache),
-            unroll=(seg.count - lo) if cfg.scan_unroll else 1,
-        )
-        new_caches.append(cache_stack)
-    logits = _logits(params, cfg, x)
-    return logits, new_caches
+    ctx = _decode_context(cfg, x, pos, effective_window(cfg, seq_hint))
+    runs = [(sj, lo, plan[sj].count) for sj, lo in _tail_segments(cfg, point)]
+    x, new_caches, routes = decode_blocks(params, cfg, x, ctx, runs,
+                                          tail_caches)
+    return _logits(params, cfg, x), new_caches, routes
 
 
 # ---------------------------------------------------------------------------

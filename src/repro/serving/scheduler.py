@@ -243,8 +243,12 @@ class ContinuousBatchingEngine:
             toks, self._keys = self._select(
                 rows, self._keys, np.asarray(slots, np.int32), temps)
             with span("sync"):
-                toks_np = np.asarray(toks)  # the step's single host sync
+                toks_np = self._fetch(toks)  # the step's single host sync
             return toks_np, toks
+
+    def _fetch(self, toks: jnp.ndarray) -> np.ndarray:
+        """The selected tokens on the host."""
+        return np.asarray(toks)
 
     def _finish_step(self, active: List[int], order: jnp.ndarray,
                      rows: jnp.ndarray) -> None:

@@ -31,6 +31,15 @@ batching engine so the decode loop itself runs across the cut:
   the live logits rows. Both take the slot mask and order as operands,
   so they compile once per session and the step launches the same
   handful of programs whatever the number of live slots.
+* **Served routing.** Where the model routes over experts, each side's
+  program also returns the experts each token chose in each of its expert
+  layers (a join's prompt, a step's one token a slot). They ride in the
+  select's fetch (no extra sync, no extra launch) and reach
+  ``_record_routes``; a decode step's are counted there, over the live
+  slots and the side's expert layers, as the stats of a span
+  ``jalad.moe.route(side, routes, experts_hit, rows)``: the routed
+  (token, held-expert) pairs, the held experts at least one of them hit,
+  and the cache positions the live slots read.
 * **Streaming wire format.** A per-session
   :class:`~repro.codec.base.StreamHeader` pins (codec, bits, frame
   shape) once at session open, so every subsequent frame costs
@@ -62,7 +71,7 @@ from repro.codec import get_codec
 from repro.core.decoupler import DecoupledPlan
 from repro.models.api import Model
 from repro.serving.scheduler import ContinuousBatchingEngine, GenRequest
-from repro.utils.trace import span
+from repro.utils.trace import mark, span
 
 if TYPE_CHECKING:
     from repro.codec import BoundaryCodec, StreamHeader, WireBlob
@@ -118,11 +127,15 @@ class TokenStreamSession(ContinuousBatchingEngine):
         self._cloud_dtype = jnp.dtype(cfg_cloud.dtype)
 
         # Named, so that the trace reads jit_prefill_head and not a lambda.
+        # Routes come out as (slots, expert layers, tokens, k), or None.
         def prefill_head(p, b):
-            return model.prefill_head(p, b, L, point)
+            boundary, caches, routes = model.prefill_head(p, b, L, point)
+            return boundary, caches, _one_slot(routes)
 
         def prefill_tail(p, x):
-            return self.cloud_model.prefill_tail(p, x, L, point)
+            logits, caches, routes = self.cloud_model.prefill_tail(
+                p, x, L, point)
+            return logits, caches, _one_slot(routes)
 
         slots = (None, 0, 0, 0)
         head = jax.vmap(
@@ -136,16 +149,18 @@ class TokenStreamSession(ContinuousBatchingEngine):
         self._frame_shape = (1, 1, int(model.cfg.d_model))
 
         def decode_head(p, last, pos, caches, mask):
-            boundary, new = head(p, last, pos, caches)
+            boundary, new, routes = head(p, last, pos, caches)
             return (self._masked_update(caches, new, mask),
-                    tuple(boundary[i] for i in range(n)))
+                    tuple(boundary[i] for i in range(n)),
+                    _each_slot(routes))
 
         def decode_tail(p, rows, order, pos, caches, mask):
             x = jnp.zeros((n,) + self._frame_shape, self._cloud_dtype)
             x = x.at[order].set(jnp.stack(rows))
-            logits, new = tail(p, x, pos, caches)
+            logits, new, routes = tail(p, x, pos, caches)
             return (self._masked_update(caches, new, mask),
-                    jnp.where(mask, pos + 1, pos), logits[order, 0, -1])
+                    jnp.where(mask, pos + 1, pos), logits[order, 0, -1],
+                    _each_slot(routes))
 
         self._prefill_head = jax.jit(prefill_head)
         self._prefill_tail = jax.jit(prefill_tail)
@@ -162,6 +177,10 @@ class TokenStreamSession(ContinuousBatchingEngine):
         self.header: "StreamHeader" = self._codec.open_stream(
             self._frame_shape, self.plan.bits)
         self.bytes_sent: int = self.header.nbytes
+        # Routes on the device until the select fetches them: (side,
+        # slots, cache rows a decode step reads or None for a join, ids).
+        self._routes: List[Tuple[str, List[int], Optional[int],
+                                 jnp.ndarray]] = []
         self.encode_groups: List[Tuple[int, List[int]]] = []
         self.tokens_out: int = 0
         self.kv_bytes_ratio: Optional[float] = None
@@ -195,14 +214,18 @@ class TokenStreamSession(ContinuousBatchingEngine):
         with span("stream.join", uid=req.uid, prompt_len=len(req.tokens)):
             batch = {"tokens": jnp.asarray(req.tokens[None, :], jnp.int32)}
             with span("stream.prefill_head"):
-                boundary, head = self._prefill_head(self.params, batch)
+                boundary, head, head_routes = self._prefill_head(
+                    self.params, batch)
             with span("codec.encode", rows=1):
                 blob = self._codec.encode(boundary, self.plan.bits)
             self.bytes_sent += blob.stream_nbytes
             with span("codec.decode", rows=1):
                 x = self._codec.decode(blob, out_dtype=self._cloud_dtype)
             with span("stream.prefill_tail"):
-                logits, tail = self._prefill_tail(self.params, x)
+                logits, tail, tail_routes = self._prefill_tail(self.params,
+                                                               x)
+            self._keep_routes("head", [slot], head_routes)
+            self._keep_routes("tail", [slot], tail_routes)
             self._head_caches = self._write_slot(self._head_caches, head,
                                                  slot)
             self._tail_caches, self._pos = self._write_slot(
@@ -231,9 +254,51 @@ class TokenStreamSession(ContinuousBatchingEngine):
         rows and the step's (mask, order) operands."""
         with span("stream.head", slots=len(active)):
             mask, order = self._slot_order(active)
-            self._head_caches, boundary = self._decode_head(
+            self._head_caches, boundary, routes = self._decode_head(
                 self.params, self._last, self._pos, self._head_caches, mask)
+            self._keep_routes("head", active, routes, decode=True)
             return [boundary[s] for s in active], (mask, order)
+
+    def _keep_routes(self, side: str, slots: List[int],
+                     routes: Optional[jnp.ndarray],
+                     decode: bool = False) -> None:
+        """Hold a side's routes, still on the device, for the select's
+        fetch: a join's of its one slot, a decode step's of every slot (to
+        be read at the live ``slots``, with the cache positions they
+        read)."""
+        if routes is None:
+            return
+        rows = (sum(len(self._slots[s].tokens) + len(self._slots[s].out_tokens)
+                    for s in slots) if decode else None)
+        self._routes.append((side, slots, rows, routes))
+
+    def _fetch(self, toks: jnp.ndarray) -> np.ndarray:
+        """The tokens, and the routes held for this fetch with them."""
+        if not self._routes:
+            return super()._fetch(toks)
+        kept, self._routes = self._routes, []
+        toks_np, ids = jax.device_get((toks, [k[-1] for k in kept]))
+        for (side, slots, rows, _), a in zip(kept, ids):
+            self._record_routes(side, slots,
+                                a if rows is None else a[slots], rows)
+        return np.asarray(toks_np)
+
+    def _record_routes(self, side: str, slots: List[int], ids: np.ndarray,
+                       rows: Optional[int]) -> None:
+        """The experts ``slots``' tokens chose, on the host: ids (slots,
+        expert layers, tokens, k) of a join's prompt (``rows`` None) or of
+        a decode step's one token a slot, whose counts become the stats of
+        ``jalad.moe.route``."""
+        if rows is None:
+            return
+        cfg = self.model.cfg
+        local = ids - cfg.expert_lo
+        held = (local >= 0) & (local < cfg.experts_held_)
+        layer = np.broadcast_to(
+            np.arange(ids.shape[1])[None, :, None, None], ids.shape)
+        hit = np.unique(layer[held] * cfg.experts_held_ + local[held])
+        mark("moe.route", side=side, routes=int(held.sum()),
+             experts_hit=int(hit.size), rows=rows)
 
     def _account_encode(self, active: List[int],
                         blobs: Sequence["WireBlob"]) -> List[int]:
@@ -253,9 +318,10 @@ class TokenStreamSession(ContinuousBatchingEngine):
         with span("stream.tail", slots=len(active)):
             rows = tuple(xs) + (self._no_row,) * (self.cfg.max_batch
                                                   - len(xs))
-            self._tail_caches, self._pos, logits = self._decode_tail(
-                self.params, rows, order, self._pos, self._tail_caches,
-                mask)
+            self._tail_caches, self._pos, logits, routes = \
+                self._decode_tail(self.params, rows, order, self._pos,
+                                  self._tail_caches, mask)
+            self._keep_routes("tail", active, routes, decode=True)
             return logits[:len(active)]
 
     # ------------------------------------------------------------------ step
@@ -308,6 +374,17 @@ class TokenStreamSession(ContinuousBatchingEngine):
             edge_b / tpb * k, nbytes / bandwidth, cloud_b / tpb * k,
             int(nbytes), self.plan.point, self.plan.bits, self.plan.codec)
         return server.record(bd)
+
+
+def _one_slot(routes: Optional[jnp.ndarray]) -> Optional[jnp.ndarray]:
+    """A join's routes (layers, 1, S, k) as (1, layers, S, k)."""
+    return None if routes is None else jnp.swapaxes(routes, 0, 1)
+
+
+def _each_slot(routes: Optional[jnp.ndarray]) -> Optional[jnp.ndarray]:
+    """The vmapped step's routes (slots, layers, 1, 1, k) as (slots,
+    layers, 1, k)."""
+    return None if routes is None else routes[:, :, 0]
 
 
 def step_stream_group(sessions: Sequence[TokenStreamSession]

@@ -28,6 +28,8 @@ def test_all_ten_assigned_archs_registered():
         "yi-6b", "llama4-maverick-400b-a17b", "xlstm-1.3b", "qwen2-vl-7b",
         "granite-34b", "seamless-m4t-large-v2", "zamba2-2.7b", "olmo-1b",
         "qwen3-8b", "grok-1-314b",
+        # Drawn later: the published model and its one-chip expert share.
+        "moonlight-16b-a3b", "moonlight-16b-a3b-ep8",
     }
     assert set(ARCHS) == expected
 
@@ -45,6 +47,8 @@ def test_exact_assigned_dimensions():
         "olmo-1b": (16, 2048, 16, 16, 8192, 50304),
         "qwen3-8b": (36, 4096, 32, 8, 12288, 151936),
         "grok-1-314b": (64, 6144, 48, 8, 32768, 131072),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
+        "moonlight-16b-a3b-ep8": (27, 2048, 16, 16, 11264, 163840),
     }
     for arch, (L, d, h, kv, ff, v) in spec.items():
         c = get_config(arch)
@@ -57,6 +61,19 @@ def test_exact_assigned_dimensions():
     assert get_config("zamba2-2.7b").ssm_state_dim == 64
     assert get_config("qwen3-8b").qk_norm
     assert get_config("olmo-1b").norm_kind == "nonparametric"
+    for arch in ("moonlight-16b-a3b", "moonlight-16b-a3b-ep8"):
+        c = get_config(arch)
+        assert (c.kv_lora_rank, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                c.v_head_dim) == (512, 128, 64, 128)
+        assert (c.num_experts, c.experts_per_token, c.moe_d_ff,
+                c.n_shared_experts) == (64, 6, 1408, 2)
+        assert (c.scoring_func, c.routed_scaling_factor) == ("sigmoid",
+                                                             2.446)
+        assert c.block_pattern == "d" + "e" * 26
+        assert c.rope_theta == 50000.0 and not c.scale_embeddings
+    assert get_config("moonlight-16b-a3b").experts_held_ == 64
+    ep8 = get_config("moonlight-16b-a3b-ep8")
+    assert (ep8.expert_lo, ep8.experts_held_) == (0, 8)
 
 
 def test_reduced_meets_smoke_budget():
@@ -65,6 +82,19 @@ def test_reduced_meets_smoke_budget():
         assert r.d_model <= 512
         assert (r.num_layers or len(r.block_pattern)) <= 4
         assert r.num_experts <= 4
+
+
+@pytest.mark.parametrize("arch", ["moonlight-16b-a3b",
+                                  "moonlight-16b-a3b-ep8"])
+def test_reduced_keeps_latent_attention_and_the_expert_share(arch):
+    full, r = get_config(arch), get_config(arch).reduced()
+    assert r.is_mla and r.n_shared_experts == full.n_shared_experts
+    assert r.scoring_func == "sigmoid" and not r.scale_embeddings
+    assert 0 < r.experts_per_token <= r.num_experts
+    assert 0 < r.experts_held_ <= r.num_experts
+    assert r.expert_lo + r.experts_held_ <= r.num_experts
+    if full.experts_held:
+        assert r.experts_held_ < r.num_experts      # a share stays a share
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -114,7 +144,8 @@ def test_decode_step_and_cache(arch):
 
 
 @pytest.mark.parametrize("arch", ["olmo-1b", "qwen3-8b", "zamba2-2.7b",
-                                  "xlstm-1.3b"])
+                                  "xlstm-1.3b", "moonlight-16b-a3b-ep8",
+                                  "grok-1-314b"])
 def test_prefill_then_decode_matches_forward(arch):
     """Teacher-forced decode over the same tokens must reproduce the
     full-sequence forward logits (KV cache / state correctness)."""

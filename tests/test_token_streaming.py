@@ -61,15 +61,16 @@ def test_split_forward_bitwise_equals_unsplit(arch):
         _prompts(model.cfg, [6])[0][None, :], jnp.int32)}
     ref_logits, ref_caches = model.prefill(params, batch, L)
     for point in range(len(model.decoupling_points())):
-        boundary, head = model.prefill_head(params, batch, L, point)
-        logits, tail = model.prefill_tail(params, boundary, L, point)
+        boundary, head, _ = model.prefill_head(params, batch, L, point)
+        logits, tail, _ = model.prefill_tail(params, boundary, L, point)
         np.testing.assert_array_equal(np.asarray(logits),
                                       np.asarray(ref_logits))
         pos = jnp.asarray(6, jnp.int32)
         tok = jnp.asarray(ref_logits[:, -1].argmax(-1))[:, None]
         ref_step, _ = model.decode_step(params, tok, pos, ref_caches)
-        b, _ = model.decode_head(params, tok, pos, head, point, L)
-        split_step, _ = model.decode_tail(params, b, pos, tail, point, L)
+        b, *_ = model.decode_head(params, tok, pos, head, point, L)
+        split_step, *_ = model.decode_tail(params, b, pos, tail, point,
+                                           L)
         np.testing.assert_array_equal(np.asarray(split_step),
                                       np.asarray(ref_step))
 
